@@ -127,7 +127,7 @@ _MESH_SCRIPT = textwrap.dedent(
     """
     import os, time
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"  # CPU emulation, never the chip
     import json
     import numpy as np
     import jax

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import backend
 from repro.kernels.gram.ops import gram, gram_batched, gram_fused
 from repro.kernels.gram.ref import gram_ref
 from repro.kernels.rglru.ops import rglru_scan
@@ -92,9 +93,8 @@ def write_bench_snapshot(results: dict,
 
 
 def _mode() -> str:
-    """Pallas execution mode of this process: compiled on TPU, interpreter
-    everywhere else (see ops._on_tpu)."""
-    return "compiled" if jax.default_backend() == "tpu" else "interpret"
+    """Pallas execution mode of this process (``repro.kernels.backend``)."""
+    return "interpret" if backend.interpret() else "compiled"
 
 
 # --------------------------------------------------------------------------

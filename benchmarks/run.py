@@ -41,9 +41,23 @@ experiments/benchmarks/.
 import os
 import sys
 import traceback
+from pathlib import Path
+
+
+def _compile_cache() -> None:
+    """JAX's persistent compile cache: ``JAX_COMPILATION_CACHE_DIR`` when
+    set (JAX reads it itself), else a fixed, git-ignored path in the
+    checkout, so a rerun of the same checkout finds its compiled code."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    root = Path(__file__).resolve().parents[1]
+    jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
 
 
 def main() -> None:
+    _compile_cache()
     from benchmarks import (
         asynchrony, communication, consensus, convergence, generalization,
         kernels, observability, robustness, roofline, topology,

@@ -167,6 +167,9 @@ def fit(
     first argument is the RAW per-agent input X (m, N, d_in) and
     ``feature_map=`` is required — the frozen ELM hidden layer runs inside
     the Gram kernel, so H never materializes (``engine.produce_stats``).
+    With ``executor="sharded"`` the stats pass runs per shard of
+    ``mesh[agent_axes]``: each device reduces its own agents' rows, and the
+    statistics reach the runner already placed by agent.
 
     Executor-specific kwargs are validated: ``staleness``/``order`` only
     apply to "colored", ``schedule`` to "colored"/"sharded",
@@ -329,9 +332,10 @@ def fit(
     if executor == "sharded":
         exec_name = "sharded_graph" if use_graph_path else "sharded"
     with trace_ctx:
+        # sharded executors: each device reduces its own agents' rows
         stats = engine.produce_stats(
             H, T, producer=cfg.stats_producer, feature_map=feature_map,
-            precision=cfg.stats_precision,
+            precision=cfg.stats_precision, mesh=mesh, agent_axes=agent_axes,
         )
         runner = engine.make_runner(
             stats, g, cfg, executor=exec_name, mesh=mesh,

@@ -11,8 +11,11 @@ a single pure per-agent round, instead of one implementation per execution
 backend:
 
   ``sufficient_stats`` / ``sufficient_stats_fused`` / ``accumulate_stats``
-      The stats producers — the Pallas ``gram`` kernels (TPU) or their jnp
-      oracles (``use_pallas=False``).  On the Pallas path a stacked
+      The stats producers — the Pallas ``gram`` kernels, or their jnp
+      oracles.  ``use_pallas=None`` (the default) means the compiled kernel
+      on a TPU and the jnp oracle on the CPU; ``use_pallas=True`` on the
+      CPU runs the kernel in interpret mode (``repro.kernels.backend``
+      decides, in one place).  On the Pallas path a stacked
       (m, N, L) input is ONE agent-batched triangular-grid kernel launch
       (``gram_batched``: grid (m, tri, n + 1), the trailing step mirroring
       G's symmetric tiles in-kernel) rather than m vmapped launches.
@@ -23,9 +26,10 @@ backend:
       (``cfg.stats_producer`` x ``cfg.stats_precision`` — oracle relations
       asserted in tests):
 
-        materialized fp32   H computed in XLA, streamed by the triangular
-                            kernel.  The parity oracle for every row below
-                            (== ``gram_ref``, the jnp path).
+        materialized fp32   H computed in XLA, then reduced by the
+                            triangular kernel (on a TPU) or by
+                            ``gram_ref`` (the CPU default).  The parity
+                            oracle for every row below.
         materialized bf16   bf16 tiles, fp32 accumulators: half the H read
                             traffic, ~4e-3 relative error on G/R — see
                             ``benchmarks/convergence.run_precision`` for
@@ -38,7 +42,8 @@ backend:
                             truth (unbiased).
         fused fp32          H = act(X W + b) computed INSIDE the kernel
                             from raw features; H never hits HBM.
-                            Bitwise-identical to materialized fp32.
+                            Bitwise-identical to materialized fp32
+                            on H built from the kernel's tiles.
         fused bf16          in-kernel hidden tiles rounded to bf16 before
                             the MXU — matches the materialized bf16
                             stream bit for bit.
@@ -259,13 +264,15 @@ from typing import Callable, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.core import exchange
 from repro.core.graph import Graph
+from repro.kernels import backend as kernel_backend
 from repro.obs import trace as obs_trace
 from repro.obs.counters import modeled_floats_per_iter
 from repro.core.solvers import (
+    HIGHEST,
     kron_ridge_solve,
+    mm,
     sum_sylvester_cg,
     sylvester_ridge_solve,
 )
@@ -317,7 +324,7 @@ def _gram_one(H: jax.Array, T: jax.Array, use_pallas: bool,
 
 
 def sufficient_stats(
-    H: jax.Array, T: jax.Array, use_pallas: bool = False,
+    H: jax.Array, T: jax.Array, use_pallas: bool | None = None,
     precision: str = "fp32", quant_seed=0,
 ) -> SufficientStats:
     """The MATERIALIZED stats producer. H: (N, L) or (m, N, L); T matches.
@@ -331,8 +338,10 @@ def sufficient_stats(
     quantized 1-byte tiles (stochastic rounding over ``quant_seed``; see
     ``repro.kernels.gram.ops``); ``t2`` (a scalar diagnostics reduction)
     always stays fp32.  See :func:`sufficient_stats_fused` for the producer
-    that never materializes H at all.
+    that never materializes H at all.  ``use_pallas=None`` resolves by
+    backend (:func:`repro.kernels.backend.stats_use_pallas`).
     """
+    use_pallas = kernel_backend.stats_use_pallas(use_pallas)
     if H.ndim == 2:
         G, R = _gram_one(H, T, use_pallas, precision, quant_seed)
         n = jnp.asarray(H.shape[0], jnp.float32)
@@ -350,7 +359,7 @@ def sufficient_stats(
 
 
 def sufficient_stats_fused(
-    X: jax.Array, feature_map, T: jax.Array, use_pallas: bool = False,
+    X: jax.Array, feature_map, T: jax.Array, use_pallas: bool | None = None,
     precision: str = "fp32",
 ) -> SufficientStats:
     """The FUSED stats producer: statistics straight from raw features.
@@ -360,14 +369,16 @@ def sufficient_stats_fused(
     matches X's leading shape.  The hidden layer ``H = act(X W + b)`` is
     computed INSIDE the Gram kernel (``gram_fused``) and never written to
     HBM at full precision — the O(N L) materialize write + re-read of the
-    unfused pipeline disappears.  Bitwise-identical to
-    ``sufficient_stats(feature_map(X), T)`` in fp32 (asserted in tests);
+    unfused pipeline disappears.  Equal to
+    ``sufficient_stats(feature_map(X), T)`` in fp32 up to how XLA blocks
+    the hidden-layer matmul (bitwise on H built from the kernel's tiles);
     ``precision="bf16"`` rounds the in-kernel hidden tiles like the
     materialized bf16 stream.  int8 is not offered fused (its maxabs
     scale pass needs a materialized H — use the unfused int8 stream).
     """
     from repro.kernels.gram.ops import gram_fused
 
+    use_pallas = kernel_backend.stats_use_pallas(use_pallas)
     G, R = gram_fused(
         X, feature_map.W, feature_map.b, T,
         activation=feature_map.activation, precision=precision,
@@ -386,8 +397,8 @@ STATS_PRODUCERS = ("materialized", "fused")
 
 def produce_stats(
     batch: jax.Array, T: jax.Array, *, producer: str = "materialized",
-    feature_map=None, use_pallas: bool = False, precision: str = "fp32",
-    quant_seed=0,
+    feature_map=None, use_pallas: bool | None = None,
+    precision: str = "fp32", quant_seed=0, mesh=None, agent_axes=None,
 ) -> SufficientStats:
     """Dispatch ONE batch through the configured stats producer.
 
@@ -397,6 +408,11 @@ def produce_stats(
     This is the single validation point for the
     ``cfg.stats_producer`` plumbing (``dmtl_elm.fit``,
     ``data.pipeline.stream_sufficient_stats``).
+
+    ``mesh=`` / ``agent_axes=`` (the sharded executors' layout) run the
+    producer per shard under ``shard_map``: each device reduces its own
+    agents' rows and the stats come out placed by agent, so no device
+    holds the whole (m, L, L) stack.
     """
     if producer not in STATS_PRODUCERS:
         raise ValueError(
@@ -420,13 +436,27 @@ def produce_stats(
             f"producer={producer!r}"
         )
 
-    def _dispatch():
+    if (mesh is None) != (agent_axes is None):
+        raise ValueError("mesh= and agent_axes= go together")
+
+    def _produce(batch, T):
         if producer == "fused":
             return sufficient_stats_fused(batch, feature_map, T,
                                           use_pallas=use_pallas,
                                           precision=precision)
         return sufficient_stats(batch, T, use_pallas=use_pallas,
                                 precision=precision, quant_seed=quant_seed)
+
+    def _dispatch():
+        if mesh is None:
+            return _produce(batch, T)
+        from jax.sharding import PartitionSpec as P
+
+        spec = P(tuple(agent_axes))
+        return jax.jit(jax.shard_map(
+            _produce, mesh=mesh, check_vma=False, in_specs=(spec, spec),
+            out_specs=SufficientStats(spec, spec, spec, spec),
+        ))(batch, T)
 
     tr = obs_trace.current()
     if tr is None:
@@ -450,7 +480,7 @@ def init_stats(m: int, L: int, d: int, dtype=jnp.float32) -> SufficientStats:
 
 def accumulate_stats(
     stats: SufficientStats, H: jax.Array, T: jax.Array,
-    use_pallas: bool = False, precision: str = "fp32",
+    use_pallas: bool | None = None, precision: str = "fp32",
     producer: str = "materialized", feature_map=None, quant_seed=0,
 ) -> SufficientStats:
     """Fold one feature batch into running stats (streaming accumulation).
@@ -475,7 +505,7 @@ def _kahan_add(total: jax.Array, comp: jax.Array, delta: jax.Array):
 
 def accumulate_stats_chunked(
     stats: SufficientStats, H: jax.Array, T: jax.Array,
-    chunk: int, use_pallas: bool = False, precision: str = "fp32",
+    chunk: int, use_pallas: bool | None = None, precision: str = "fp32",
     compensated: bool = False, producer: str = "materialized",
     feature_map=None, quant_seed=0,
 ) -> SufficientStats:
@@ -574,9 +604,9 @@ def fit_error_from_stats(
     """
     if U.ndim == 2:
         U = jnp.broadcast_to(U, (A.shape[0],) + U.shape)
-    UtGU = jnp.einsum("mlr,mlk,mks->mrs", U, stats.G, U)
-    quad = jnp.einsum("mrs,msd,mrd->", UtGU, A, A)
-    cross = jnp.einsum("mlr,mld,mrd->", U, stats.R, A)
+    UtGU = jnp.einsum("mlr,mlk,mks->mrs", U, stats.G, U, precision=HIGHEST)
+    quad = jnp.einsum("mrs,msd,mrd->", UtGU, A, A, precision=HIGHEST)
+    cross = jnp.einsum("mlr,mld,mrd->", U, stats.R, A, precision=HIGHEST)
     t2 = jnp.sum(jnp.asarray(stats.t2, jnp.float32))
     return 0.5 * (quad - 2.0 * cross + t2)
 
@@ -624,8 +654,9 @@ class ConsensusConfig:
     # (half of bf16 again; unfused path only).
     stats_precision: str = "fp32"
     # Stats producer for entry points that reduce raw data to stats:
-    # "materialized" computes H = g(X W + b) in XLA and streams it through
-    # the triangular kernel (the parity oracle); "fused" computes the
+    # "materialized" computes H = g(X W + b) in XLA and reduces it with the
+    # triangular kernel on a TPU (the jnp oracle on the CPU — the parity
+    # oracle, see ``repro.kernels.backend``); "fused" computes the
     # hidden layer INSIDE the Gram kernel from raw inputs (needs a
     # feature_map= at the call site), so H never hits HBM — see
     # ``produce_stats`` / ``sufficient_stats_fused``.
@@ -701,9 +732,13 @@ def register_u_solver(name: str, fn: Callable) -> None:
 def hoist_precomp(stats: SufficientStats, cfg: ConsensusConfig):
     """Iteration-invariant precomputation for the configured U-solver
     (eigh(G) for ``sylvester``; batched over any leading agent axes)."""
-    if cfg.u_solver == "sylvester" and not cfg.first_order:
+    if _hoists_eigh(cfg):
         return jnp.linalg.eigh(stats.G)
     return None
+
+
+def _hoists_eigh(cfg: ConsensusConfig) -> bool:
+    return cfg.u_solver == "sylvester" and not cfg.first_order
 
 
 # --------------------------------------------------------------------------
@@ -748,11 +783,11 @@ def agent_update(
     rho, mu1 = cfg.rho, cfg.mu1
     p_t = msgs.tau - rho * msgs.deg if cfg.prox == "prox_linear" else msgs.tau
 
-    M = A @ A.T                                            # (r, r)
-    rhs = stats.R @ A.T + rho * msgs.neigh_sum - msgs.ct_lam + p_t * U
+    M = mm(A, A.T)                                         # (r, r)
+    rhs = mm(stats.R, A.T) + rho * msgs.neigh_sum - msgs.ct_lam + p_t * U
     if cfg.first_order:
         # eq. (23): prox-linear collapses the solve to a scaled gradient step
-        grad_f = stats.G @ U @ M
+        grad_f = mm(mm(stats.G, U), M)
         U_new = (rhs - grad_f - (mu1 / m_total) * U) / (rho * msgs.deg + p_t)
     else:
         if cfg.u_solver not in U_SOLVERS:
@@ -764,9 +799,9 @@ def agent_update(
         U_new = U_SOLVERS[cfg.u_solver](stats.G, M, rhs, c_t, precomp)
 
     # A update (eq. 21), purely local, on the fresh U
-    Ga = U_new.T @ stats.G @ U_new
+    Ga = mm(mm(U_new.T, stats.G), U_new)
     Ga = Ga + (msgs.zeta + cfg.mu2) * jnp.eye(cfg.r, dtype=U.dtype)
-    A_new = jnp.linalg.solve(Ga, U_new.T @ stats.R + msgs.zeta * A)
+    A_new = jnp.linalg.solve(Ga, mm(U_new.T, stats.R) + msgs.zeta * A)
     return U_new, A_new
 
 
@@ -1670,9 +1705,9 @@ def _local_objective(
     threaded through the shard_map (they make ``||T_t||^2`` available
     without revisiting data).  Summed over agents this equals
     :func:`objective_from_stats` exactly."""
-    UtGU = U.T @ (stats_t.G @ U)
-    quad = jnp.sum((UtGU @ A) * A)                  # tr(A^T U^T G U A)
-    cross = jnp.sum((U.T @ stats_t.R) * A)          # tr(A^T U^T R)
+    UtGU = mm(U.T, mm(stats_t.G, U))
+    quad = jnp.sum(mm(UtGU, A) * A)                 # tr(A^T U^T G U A)
+    cross = jnp.sum(mm(U.T, stats_t.R) * A)         # tr(A^T U^T R)
     t2 = jnp.asarray(stats_t.t2, jnp.float32)
     return (
         0.5 * (quad - 2.0 * cross + t2)
@@ -1854,6 +1889,50 @@ def ring_iteration(
     return AgentState(U_new, A_new, lam_new), diag
 
 
+def _place_by_agent(stats: SufficientStats, mesh, axes_t, cfg):
+    """(G, R, n, t2, precomp) laid out one agent block per shard of
+    ``axes_t``.
+
+    Scalar n/t2 (the (G, R)-only construction) broadcast to per-agent
+    leaves so they shard alongside G/R.  Stats that already arrive placed
+    by agent (``produce_stats(..., mesh=)``) are not moved; anything else
+    is scattered ONCE here, not resharded on every segment.  ``precomp``
+    is the U-solver's hoisted set-up (:func:`hoist_precomp`) as a tuple of
+    agent-placed leaves — ``(eigenvalues, eigenvectors)`` for sylvester,
+    ``()`` otherwise — computed once per runner rather than inside every
+    segment program."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    m = stats.G.shape[0]
+    sh = NamedSharding(mesh, P(axes_t))
+    n_all = jnp.broadcast_to(jnp.asarray(stats.n, jnp.float32), (m,))
+    t2_all = jnp.broadcast_to(jnp.asarray(stats.t2, jnp.float32), (m,))
+    G_all, R_all, n_all, t2_all = jax.device_put(
+        (stats.G, stats.R, n_all, t2_all), sh
+    )
+    pre_all = _sharded_eigh(mesh, axes_t)(G_all) if _hoists_eigh(cfg) else ()
+    return G_all, R_all, n_all, t2_all, pre_all
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_eigh(mesh, axes_t):
+    """eigh of every shard's own G blocks, placed by agent: one compiled
+    program per mesh layout, shared by every sharded runner built on it
+    (the batched eigh is the long compile of the sylvester solver)."""
+    from jax.sharding import PartitionSpec as P
+
+    spec = P(axes_t)
+    return jax.jit(jax.shard_map(
+        lambda G: tuple(jnp.linalg.eigh(G)), mesh=mesh, check_vma=False,
+        in_specs=spec, out_specs=(spec, spec),
+    ))
+
+
+def _shard_precomp(pre_blk):
+    """This shard's hoisted precomp from its ``_place_by_agent`` block."""
+    return tuple(x[0] for x in pre_blk) or None
+
+
 def _make_sharded_runner(
     stats: SufficientStats,
     mesh: jax.sharding.Mesh,
@@ -1869,14 +1948,12 @@ def _make_sharded_runner(
         raise ValueError(f"m={m} must equal prod(agent axes)={n_agents}")
     L, d, r = stats.G.shape[-1], stats.R.shape[-1], cfg.r
     dtype = stats.G.dtype
-    # normalize scalar n/t2 (the (G, R)-only construction) to per-agent
-    # leaves so they shard alongside G/R instead of being silently dropped
-    n_all = jnp.broadcast_to(jnp.asarray(stats.n, jnp.float32), (m,))
-    t2_all = jnp.broadcast_to(jnp.asarray(stats.t2, jnp.float32), (m,))
-
     axes_t = tuple(agent_axes)
     spec_batched = P(axes_t)
     n_axes = len(agent_axes)
+    G_all, R_all, n_all, t2_all, pre_all = _place_by_agent(
+        stats, mesh, axes_t, cfg
+    )
 
     def init_fn():
         # the stacked all-ones/zeros state placed shard-per-agent; feeding
@@ -1897,11 +1974,12 @@ def _make_sharded_runner(
         )
 
     def segment_fn(state, n):
-        def body(G_blk, R_blk, n_blk, t2_blk, U_blk, A_blk, lam_blk):
+        def body(G_blk, R_blk, n_blk, t2_blk, pre_blk, U_blk, A_blk,
+                 lam_blk):
             stats_t = SufficientStats(
                 G=G_blk[0], R=R_blk[0], n=n_blk[0], t2=t2_blk[0]
             )
-            precomp = hoist_precomp(stats_t, cfg)  # eigh ONCE, outside scan
+            precomp = _shard_precomp(pre_blk)
 
             def step(carry, _):
                 new, diag = ring_iteration(
@@ -1919,16 +1997,18 @@ def _make_sharded_runner(
             diags = jax.tree_util.tree_map(lambda x: x[:, None], diags)
             return final.U[None], final.A[None], final.lam[None], diags
 
-        shard_fn = compat.shard_map(
+        shard_fn = jax.shard_map(
             body,
             mesh=mesh,
-            in_specs=(spec_batched,) * 7,
+            check_vma=False,
+            in_specs=(spec_batched,) * 8,
             out_specs=(
                 spec_batched, spec_batched, spec_batched, P(None, axes_t),
             ),
         )
         U, A, lam, diags = shard_fn(
-            stats.G, stats.R, n_all, t2_all, state.U, state.A, state.lam
+            G_all, R_all, n_all, t2_all, pre_all, state.U, state.A,
+            state.lam,
         )
         diags = _assemble_sharded_diags(
             diags, len(torus_edges(sizes)), L * cfg.r
@@ -2036,8 +2116,9 @@ def _make_sharded_graph_runner(
     dtype = stats.G.dtype
     axes_t = tuple(agent_axes)
 
-    n_all = jnp.broadcast_to(jnp.asarray(stats.n, jnp.float32), (m,))
-    t2_all = jnp.broadcast_to(jnp.asarray(stats.t2, jnp.float32), (m,))
+    G_all, R_all, n_all, t2_all, pre_all = _place_by_agent(
+        stats, mesh, axes_t, cfg
+    )
     deg_all = jnp.asarray(g.degrees(), dtype)                    # (m,)
     # proximal weights resolved EXACTLY like the dense executor (scalar tau
     # -> tau + d_t, per-agent (m,) arrays passed through) and shipped as
@@ -2123,13 +2204,13 @@ def _make_sharded_graph_runner(
             lam_hist=sh if (tape is not None and aged_duals) else None,
         )
 
-    def body(G_blk, R_blk, n_blk, t2_blk, deg_blk, tau_blk, zeta_blk,
-             slot_blk, own_blk, pmask_blk, rmask_blk, U_blk, A_blk, lam_blk,
-             *, n_seg):
+    def body(G_blk, R_blk, n_blk, t2_blk, pre_blk, deg_blk, tau_blk,
+             zeta_blk, slot_blk, own_blk, pmask_blk, rmask_blk, U_blk, A_blk,
+             lam_blk, *, n_seg):
         stats_t = SufficientStats(
             G=G_blk[0], R=R_blk[0], n=n_blk[0], t2=t2_blk[0]
         )
-        precomp = hoist_precomp(stats_t, cfg)   # eigh ONCE, outside the scan
+        precomp = _shard_precomp(pre_blk)
         deg_t, tau_t, zeta_t = deg_blk[0], tau_blk[0], zeta_blk[0]
         slots, own, pmask = slot_blk[0], own_blk[0], pmask_blk[0]
         rmask = rmask_blk[0]
@@ -2226,9 +2307,9 @@ def _make_sharded_graph_runner(
         driver).  Mirrors fit_async tick semantics: membership join
         warm-start, straggler freeze, synchronous true-residual duals with
         dead-edge masking, optional aged-dual shipping."""
-        (G_blk, R_blk, n_blk, t2_blk, deg_blk, tau_blk, zeta_blk,
-         slot_blk, own_blk, U_blk, A_blk, lam_blk, hist_blk) = ops[:13]
-        idx = 13
+        (G_blk, R_blk, n_blk, t2_blk, pre_blk, deg_blk, tau_blk, zeta_blk,
+         slot_blk, own_blk, U_blk, A_blk, lam_blk, hist_blk) = ops[:14]
+        idx = 14
         lam_hist_blk = None
         if aged_duals:
             lam_hist_blk = ops[idx]
@@ -2248,7 +2329,7 @@ def _make_sharded_graph_runner(
         stats_t = SufficientStats(
             G=G_blk[0], R=R_blk[0], n=n_blk[0], t2=t2_blk[0]
         )
-        precomp = hoist_precomp(stats_t, cfg)
+        precomp = _shard_precomp(pre_blk)
         deg_t, tau_t, zeta_t = deg_blk[0], tau_blk[0], zeta_blk[0]
         slots, own = slot_blk[0], own_blk[0]
         init_u = jnp.ones((L, r), dtype)        # the all-ones U^0 publish
@@ -2422,17 +2503,18 @@ def _make_sharded_graph_runner(
 
     def segment_fn(state, n):
         if tape is None:
-            shard_fn = compat.shard_map(
+            shard_fn = jax.shard_map(
                 functools.partial(body, n_seg=n),
                 mesh=mesh,
-                in_specs=(spec_batched,) * 14,
+                check_vma=False,
+                in_specs=(spec_batched,) * 15,
                 out_specs=(
                     spec_batched, spec_batched, spec_batched,
                     P(None, axes_t),
                 ),
             )
             U, A, lam, diags = shard_fn(
-                stats.G, stats.R, n_all, t2_all, deg_all, tau_all,
+                G_all, R_all, n_all, t2_all, pre_all, deg_all, tau_all,
                 zeta_all, slot_all, own_all, pmask_all, rmask_all,
                 state.U, state.A, state.lam
             )
@@ -2453,10 +2535,11 @@ def _make_sharded_graph_runner(
         if k0 > 0 and n > 0:
             _revalidate_suffix(k0, n)
         ops = [
-            stats.G, stats.R, n_all, t2_all, deg_all, tau_all, zeta_all,
-            slot_all, own_all, state.U, state.A, state.lam, state.hist,
+            G_all, R_all, n_all, t2_all, pre_all, deg_all, tau_all,
+            zeta_all, slot_all, own_all, state.U, state.A, state.lam,
+            state.hist,
         ]
-        specs = [spec_batched] * 13
+        specs = [spec_batched] * 14
         if aged_duals:
             ops.append(state.lam_hist)
             specs.append(spec_batched)
@@ -2484,9 +2567,10 @@ def _make_sharded_graph_runner(
         specs.append(P(None))
         out_specs = [spec_batched] * (5 if aged_duals else 4)
         out_specs.append(P(None, axes_t))
-        shard_fn = compat.shard_map(
+        shard_fn = jax.shard_map(
             functools.partial(tape_body, n_seg=n),
             mesh=mesh,
+            check_vma=False,
             in_specs=tuple(specs),
             out_specs=tuple(out_specs),
         )

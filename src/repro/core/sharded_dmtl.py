@@ -36,7 +36,6 @@ from typing import Optional, Sequence
 
 import jax
 
-from repro import compat  # noqa: F401  (installs shard_map/pcast shims)
 from repro.core import engine
 from repro.core.engine import AgentState as ShardedDMTLState  # noqa: F401
 from repro.core.engine import ConsensusConfig as DMTLELMConfig

@@ -23,6 +23,18 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 
+# Every matmul of the ADMM path states its precision.  On a TPU the DEFAULT
+# precision of an fp32 matmul is one bf16 MXU pass: on a v5e it moved the
+# objective of the first two ADMM iterations by 4e-2 relative and the fits
+# went to NaN (chip_smoke.py, as coded vs under HIGHEST).  The CPU computes
+# fp32 matmuls in fp32 either way, so CPU numbers do not change.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def mm(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at HIGHEST precision."""
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 def ridge_solve(H: jax.Array, T: jax.Array, mu: float) -> jax.Array:
     """Closed-form regularized ELM solve (paper eq. 4): (H^T H + mu I)^-1 H^T T.
@@ -30,8 +42,8 @@ def ridge_solve(H: jax.Array, T: jax.Array, mu: float) -> jax.Array:
     Uses Cholesky; G = H^T H + mu I is SPD for mu > 0.
     """
     L = H.shape[-1]
-    G = H.T @ H + mu * jnp.eye(L, dtype=H.dtype)
-    rhs = H.T @ T
+    G = mm(H.T, H) + mu * jnp.eye(L, dtype=H.dtype)
+    rhs = mm(H.T, T)
     cho = jax.scipy.linalg.cho_factor(G)
     return jax.scipy.linalg.cho_solve(cho, rhs)
 
@@ -58,7 +70,8 @@ def kron_ridge_solve(
         Ms = Ms[None]
     L, r = R.shape
     # vec(G U M) = (M^T kron G) vec(U); M symmetric.
-    K = jnp.einsum("tij,tkl->ikjl", Ms, Gs).reshape(L * r, L * r)
+    K = jnp.einsum("tij,tkl->ikjl", Ms, Gs,
+                   precision=HIGHEST).reshape(L * r, L * r)
     K = K + c * jnp.eye(L * r, dtype=R.dtype)
     v = jnp.linalg.solve(K, _vec_cm(R))
     return _unvec_cm(v, L, r)
@@ -80,9 +93,9 @@ def sylvester_ridge_solve(
     else:
         dg, qg = eig_g
     dm, qm = jnp.linalg.eigh(M)
-    Rt = qg.T @ R @ qm
+    Rt = mm(mm(qg.T, R), qm)
     denom = dg[:, None] * dm[None, :] + c
-    return qg @ (Rt / denom) @ qm.T
+    return mm(mm(qg, Rt / denom), qm.T)
 
 
 def cg_solve(
@@ -114,9 +127,9 @@ def cg_solve(
     r0 = b - matvec(x0)
     z0 = apply_m(r0)
     p0 = z0
-    rz0 = jnp.vdot(r0, z0).real
-    rs0 = jnp.vdot(r0, r0).real
-    b2 = jnp.maximum(jnp.vdot(b, b).real, 1e-30)
+    rz0 = jnp.vdot(r0, z0, precision=HIGHEST).real
+    rs0 = jnp.vdot(r0, r0, precision=HIGHEST).real
+    b2 = jnp.maximum(jnp.vdot(b, b, precision=HIGHEST).real, 1e-30)
 
     def cond(state):
         _, _, _, _, rs, it = state
@@ -125,12 +138,13 @@ def cg_solve(
     def body(state):
         x, r, p, rz, rs, it = state
         ap = matvec(p)
-        alpha = rz / jnp.maximum(jnp.vdot(p, ap).real, 1e-30)
+        pap = jnp.vdot(p, ap, precision=HIGHEST).real
+        alpha = rz / jnp.maximum(pap, 1e-30)
         x = x + alpha * p
         r = r - alpha * ap
         z = apply_m(r)
-        rz_new = jnp.vdot(r, z).real
-        rs_new = jnp.vdot(r, r).real
+        rz_new = jnp.vdot(r, z, precision=HIGHEST).real
+        rs_new = jnp.vdot(r, r, precision=HIGHEST).real
         p = z + (rz_new / jnp.maximum(rz, 1e-30)) * p
         return x, r, p, rz_new, rs_new, it + 1
 
@@ -157,7 +171,7 @@ def gram_diag_precond(
         Ms = Ms[None]
     dG = jnp.diagonal(Gs, axis1=-2, axis2=-1)   # (m, L)
     dM = jnp.diagonal(Ms, axis1=-2, axis2=-1)   # (m, r)
-    denom = jnp.einsum("tl,ts->ls", dG, dM) + c
+    denom = jnp.einsum("tl,ts->ls", dG, dM, precision=HIGHEST) + c
     denom = jnp.maximum(denom, 1e-30)
     return lambda v: v / denom
 
@@ -178,7 +192,8 @@ def sum_sylvester_cg(
         Ms = Ms[None]
 
     def matvec(u):
-        return jnp.einsum("tij,jk,tkl->il", Gs, u, Ms) + c * u
+        return jnp.einsum("tij,jk,tkl->il", Gs, u, Ms,
+                          precision=HIGHEST) + c * u
 
     if precond is None:
         pc = None

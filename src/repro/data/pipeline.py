@@ -76,7 +76,7 @@ def stream_sufficient_stats(
     stats=None,
     *,
     chunk: Optional[int] = None,
-    use_pallas: bool = False,
+    use_pallas: bool | None = None,
     precision: str = "fp32",
     compensated: bool = False,
     producer: str = "materialized",

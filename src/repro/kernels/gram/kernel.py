@@ -46,14 +46,16 @@ work on mirrored tiles.  Two kernels:
     entirely.  Padded rows/columns are masked to exact zero in-kernel
     (``act(0) != 0`` for sigmoid-family activations, so zero-padding the
     inputs alone would corrupt the statistics); ``d_in`` is NOT padded, so
-    the per-tile contraction is bitwise-identical to the materialized
-    ``act(X @ W + b)`` and the fused fp32 producer matches the
-    materialized kernel bit for bit (asserted in tests).
+    each hidden tile is the same (BN, d_in) x (d_in, BL) product as the
+    materialized ``act(X @ W + b)`` built from those tiles, and the fused
+    fp32 producer matches the materialized kernel on it bit for bit
+    (asserted in tests).
 
 Mixed precision: the kernels stream H / T tiles in their *input* dtype and
-hand them straight to ``lax.dot_general(..., preferred_element_type=f32)``,
-so bf16 inputs take the native bf16-multiply / fp32-accumulate MXU path
-(half the HBM read traffic) while the G / R accumulators stay fp32 in VMEM;
+hand them straight to the MXU with fp32 accumulation (``_dot``): bf16
+inputs take the native bf16-multiply / fp32-accumulate path (half the HBM
+read traffic), fp32 inputs contract at HIGHEST precision (the TPU's
+default would round them to bf16), and the G / R accumulators stay fp32;
 int8 quarters the read traffic again through ``gram_pallas_tri_q``.
 ``ops.gram(..., precision=...)`` does the downcast / quantization at the op
 boundary.
@@ -107,6 +109,19 @@ def _tri_decode(t):
     return i, t - i * (i + 1) // 2
 
 
+def _dot(a, b, contract):
+    """MXU contraction into an fp32 accumulator.  fp32 operands contract at
+    HIGHEST: the TPU's DEFAULT precision for them is one bf16 pass, which
+    on a v5e gave fp32 statistics the error of bf16 ones (2e-4 relative
+    on G against a HIGHEST einsum, chip_smoke.py).  bf16 operands are
+    exact in one pass."""
+    precision = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), precision=precision,
+        preferred_element_type=jnp.float32,
+    )
+
+
 def _gram_kernel(h_i_ref, h_j_ref, t_ref, g_ref, r_ref, *, n_steps):
     n = pl.program_id(2)
     j = pl.program_id(1)
@@ -117,10 +132,7 @@ def _gram_kernel(h_i_ref, h_j_ref, t_ref, g_ref, r_ref, *, n_steps):
 
     hi = h_i_ref[...]   # (BN, BL) rows n, cols i — input dtype (f32 or bf16)
     hj = h_j_ref[...]   # (BN, BL) rows n, cols j
-    g_ref[...] += jax.lax.dot_general(
-        hi, hj, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    g_ref[...] += _dot(hi, hj, ((0,), (0,)))
 
     @pl.when(j == 0)
     def _cross():
@@ -129,10 +141,7 @@ def _gram_kernel(h_i_ref, h_j_ref, t_ref, g_ref, r_ref, *, n_steps):
             r_ref[...] = jnp.zeros_like(r_ref)
 
         t = t_ref[...]  # (BN, D)
-        r_ref[...] += jax.lax.dot_general(
-            hi, t, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        r_ref[...] += _dot(hi, t, ((0,), (0,)))
 
 
 def gram_pallas(H: jax.Array, T: jax.Array, *, block_l: int = 128,
@@ -185,10 +194,7 @@ def _gram_tri_kernel(h_i_ref, h_j_ref, t_ref, g_ref, r_ref, acc_ref, *,
     def _accumulate():
         hi = h_i_ref[0]  # (BN, BL) rows n, cols i — input dtype (f32/bf16)
         hj = h_j_ref[0]  # (BN, BL) rows n, cols j <= i
-        acc_ref[...] += jax.lax.dot_general(
-            hi, hj, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _dot(hi, hj, ((0,), (0,)))
 
         # R rides the diagonal-start column of each row: the j == 0 pair is
         # the FIRST tri index of row i, so the (a, i, 0) R tile initializes
@@ -200,10 +206,7 @@ def _gram_tri_kernel(h_i_ref, h_j_ref, t_ref, g_ref, r_ref, acc_ref, *,
                 r_ref[...] = jnp.zeros_like(r_ref)
 
             t = t_ref[0]    # (BN, D)
-            r_ref[0] += jax.lax.dot_general(
-                hi, t, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            r_ref[0] += _dot(hi, t, ((0,), (0,)))
 
     # Flush the finished accumulator to the (i, j) tile on the LAST sample
     # step, then write its transpose to the (j, i) tile on the extra mirror
@@ -285,10 +288,10 @@ def gram_pallas_tri(H: jax.Array, T: jax.Array, *, block_l: int = 128,
     )(H, H, T)
 
 
-def _gram_tri_q_kernel(h_i_ref, h_j_ref, s_i_ref, s_j_ref, t_ref, g_ref,
-                       r_ref, acc_ref, *, n_steps):
+def _gram_tri_q_kernel(h_i_ref, h_j_ref, s_ref, t_ref, g_ref, r_ref,
+                       acc_ref, *, n_steps):
     n = pl.program_id(2)
-    _, j = _tri_decode(pl.program_id(1))
+    i, j = _tri_decode(pl.program_id(1))
 
     @pl.when(n == 0)
     def _init():
@@ -298,8 +301,8 @@ def _gram_tri_q_kernel(h_i_ref, h_j_ref, s_i_ref, s_j_ref, t_ref, g_ref,
     def _accumulate():
         hi = h_i_ref[0]                 # (BN, BL) int8
         hj = h_j_ref[0]                 # (BN, BL) int8
-        s_i = s_i_ref[0, 0, 0]          # fp32 scale of the (n, i) tile
-        s_j = s_j_ref[0, 0, 0]          # fp32 scale of the (n, j) tile
+        s_i = s_ref[0, n, i]            # fp32 scale of the (n, i) tile
+        s_j = s_ref[0, n, j]            # fp32 scale of the (n, j) tile
         # int8 x int8 -> exact int32 tile product on the MXU int path; each
         # n-step's contribution is scaled into the fp32 accumulator (scales
         # vary per n tile, so the int32 sums cannot be merged across n).
@@ -317,10 +320,7 @@ def _gram_tri_q_kernel(h_i_ref, h_j_ref, s_i_ref, s_j_ref, t_ref, g_ref,
 
             hi_dq = hi.astype(jnp.float32) * s_i    # VPU dequantize
             t = t_ref[0].astype(jnp.float32)        # (BN, D), bf16 stream
-            r_ref[0] += jax.lax.dot_general(
-                hi_dq, t, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            r_ref[0] += _dot(hi_dq, t, ((0,), (0,)))
 
     @pl.when(n == n_steps - 1)
     def _flush():
@@ -337,7 +337,8 @@ def gram_pallas_tri_q(Hq: jax.Array, scales: jax.Array, T: jax.Array, *,
     """int8-streaming triangular kernel (the recorded int8 study).
 
     Hq: (m, N, L) int8 — quantized per (block_n, block_l) tile with
-    ``scales``: (m, N/block_n, L/block_l) fp32 (see ``ops.quantize_tiles``);
+    ``scales``: (m, N/block_n, L/block_l) fp32 (see ``ops.quantize_tiles``),
+    each agent's table held in SMEM and read as scalars;
     T: (m, N, D) bf16.  Same grid / mirror structure as
     :func:`gram_pallas_tri`; H read traffic is 1 byte/element (4x less than
     fp32, 2x less than bf16) while G/R stay fp32.
@@ -356,14 +357,6 @@ def gram_pallas_tri_q(Hq: jax.Array, scales: jax.Array, T: jax.Array, *,
         _, j = _tri_decode(t)
         return (a, jnp.minimum(n, nn - 1), j)
 
-    def s_row_spec(a, t, n):
-        i, _ = _tri_decode(t)
-        return (a, jnp.minimum(n, nn - 1), i)
-
-    def s_col_spec(a, t, n):
-        _, j = _tri_decode(t)
-        return (a, jnp.minimum(n, nn - 1), j)
-
     def g_spec(a, t, n):
         i, j = _tri_decode(t)
         mirror = n == nn
@@ -379,8 +372,10 @@ def gram_pallas_tri_q(Hq: jax.Array, scales: jax.Array, T: jax.Array, *,
         in_specs=[
             pl.BlockSpec((1, block_n, block_l), h_row_spec),
             pl.BlockSpec((1, block_n, block_l), h_col_spec),
-            pl.BlockSpec((1, 1, 1), s_row_spec),
-            pl.BlockSpec((1, 1, 1), s_col_spec),
+            # one agent's whole (nn, nl) scale table in SMEM, read as
+            # scalars: a (1, 1, 1) VMEM block is not (8, 128)-aligned
+            pl.BlockSpec((1, nn, nl), lambda a, t, n: (a, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_n, D), t_spec),
         ],
         out_specs=[
@@ -393,12 +388,12 @@ def gram_pallas_tri_q(Hq: jax.Array, scales: jax.Array, T: jax.Array, *,
         ],
         scratch_shapes=[pltpu.VMEM((block_l, block_l), jnp.float32)],
         interpret=interpret,
-    )(Hq, Hq, scales, scales, T)
+    )(Hq, Hq, scales, T)
 
 
 def _gram_fused_kernel(x_ref, w_i_ref, w_j_ref, b_i_ref, b_j_ref, t_ref,
-                       g_ref, r_ref, acc_ref, *, n_steps, n_true, l_true,
-                       block_n, block_l, activation, compute_dtype):
+                       g_ref, r_ref, acc_ref, hi_ref, *, n_steps, n_true,
+                       l_true, block_n, block_l, activation, compute_dtype):
     n = pl.program_id(2)
     i, j = _tri_decode(pl.program_id(1))
     act = ACTIVATIONS[activation]
@@ -422,10 +417,7 @@ def _gram_fused_kernel(x_ref, w_i_ref, w_j_ref, b_i_ref, b_j_ref, t_ref,
         ) < n_true
 
         def hidden(w_ref, b_ref, col):
-            h = act(jax.lax.dot_general(
-                x, w_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) + b_ref[...])
+            h = act(_dot(x, w_ref[...], ((1,), (0,))) + b_ref[...])
             col_ok = (
                 col * block_l
                 + jax.lax.broadcasted_iota(jnp.int32, (1, block_l), 1)
@@ -435,10 +427,7 @@ def _gram_fused_kernel(x_ref, w_i_ref, w_j_ref, b_i_ref, b_j_ref, t_ref,
 
         hi = hidden(w_i_ref, b_i_ref, i)
         hj = hidden(w_j_ref, b_j_ref, j)
-        acc_ref[...] += jax.lax.dot_general(
-            hi, hj, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[...] += _dot(hi, hj, ((0,), (0,)))
 
         @pl.when(j == 0)
         def _cross():
@@ -446,11 +435,14 @@ def _gram_fused_kernel(x_ref, w_i_ref, w_j_ref, b_i_ref, b_j_ref, t_ref,
             def _init_r():
                 r_ref[...] = jnp.zeros_like(r_ref)
 
+            # R contracts the row tile a second time.  It goes through VMEM
+            # scratch rather than reusing the value: the TPU compiler
+            # refuses one computed value feeding two transposed-operand
+            # matmuls (a RET_CHECK in its MXU transpose assignment).  Store
+            # and load are exact, so the products are unchanged.
+            hi_ref[...] = hi
             t = t_ref[0]    # (BN, D) — already masked-by-padding (zeros)
-            r_ref[0] += jax.lax.dot_general(
-                hi, t, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            r_ref[0] += _dot(hi_ref[...], t, ((0,), (0,)))
 
     @pl.when(n == n_steps - 1)
     def _flush():
@@ -459,6 +451,19 @@ def _gram_fused_kernel(x_ref, w_i_ref, w_j_ref, b_i_ref, b_j_ref, t_ref,
     @pl.when(n == n_steps)
     def _mirror():
         g_ref[0] = acc_ref[...].T
+
+
+def _fused_vmem_bytes(block_n: int, block_l: int, d_in: int) -> int:
+    """Scoped-VMEM limit for the fused kernel: the v5e compiler's
+    requirement plus a quarter.  The requirement is one term in ``d_in`` —
+    the (block_n + 2 block_l) x d_in elements of the X tile and the two W
+    panels, at 56/3 bytes each (double-buffered fp32 plus the bf16 parts of
+    the HIGHEST contraction) — and under 1 MiB for the G/R tiles and
+    scratch.  The compiler reported 28.6 MiB at d_in = 2048 and 56.6 MiB
+    at 4096 with (512, 128) blocks, above the default scoped limit and
+    under the 128 MiB of a v5e core."""
+    need = (block_n + 2 * block_l) * d_in * 56 // 3 + (1 << 20)
+    return need * 5 // 4
 
 
 def gram_pallas_fused(X: jax.Array, W: jax.Array, b: jax.Array,
@@ -535,6 +540,11 @@ def gram_pallas_fused(X: jax.Array, W: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((m, L, L), jnp.float32),
             jax.ShapeDtypeStruct((m, L, D), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_l, block_l), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_l, block_l), jnp.float32),
+            pltpu.VMEM((block_n, block_l), compute_dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_fused_vmem_bytes(block_n, block_l, d_in)),
         interpret=interpret,
     )(X, W, W, b, b, T)

@@ -1,5 +1,6 @@
 """Public fused-Gram ops: padding, block-size policy, precision casting /
-int8 tile quantization, CPU interpret fallback.
+int8 tile quantization.  Compiled on a TPU, interpreted on the CPU
+(``repro.kernels.backend`` decides).
 
 ``gram``        — unbatched (N, L) entry point; a thin wrapper that runs the
                   agent-batched triangular kernel with a singleton agent axis
@@ -50,6 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.gram.kernel import (
     gram_pallas,
     gram_pallas_fused,
@@ -60,10 +62,6 @@ from repro.kernels.gram.ref import gram_fused_ref, gram_ref, int8_emulated_ref
 
 PRECISIONS = ("fp32", "bf16", "int8")
 FUSED_PRECISIONS = ("fp32", "bf16")
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -186,7 +184,8 @@ def gram(H: jax.Array, T: jax.Array, *, block_l: int = 128,
     Hp = jnp.pad(H, ((0, pad_n), (0, pad_l)))
     Tp = jnp.pad(T, ((0, pad_n), (0, 0)))
     G, R = gram_pallas(
-        Hp, Tp, block_l=block_l, block_n=block_n, interpret=not _on_tpu()
+        Hp, Tp, block_l=block_l, block_n=block_n,
+        interpret=backend.interpret(),
     )
     return G[:L, :L], R[:L]
 
@@ -229,7 +228,7 @@ def gram_batched(H: jax.Array, T: jax.Array, *, block_l: int = 128,
         Hq, scales = quantize_tiles(Hp, resolved_bn, block_l, quant_seed)
         G, R = gram_pallas_tri_q(
             Hq, scales, Tp, block_l=block_l, block_n=resolved_bn,
-            interpret=not _on_tpu(),
+            interpret=backend.interpret(),
         )
         return G[:, :L, :L], R[:, :L]
     if force_ref:
@@ -242,7 +241,8 @@ def gram_batched(H: jax.Array, T: jax.Array, *, block_l: int = 128,
     Hp = jnp.pad(H, ((0, 0), (0, pad_n), (0, pad_l)))
     Tp = jnp.pad(T, ((0, 0), (0, pad_n), (0, 0)))
     G, R = gram_pallas_tri(
-        Hp, Tp, block_l=block_l, block_n=block_n, interpret=not _on_tpu()
+        Hp, Tp, block_l=block_l, block_n=block_n,
+        interpret=backend.interpret(),
     )
     return G[:, :L, :L], R[:, :L]
 
@@ -262,9 +262,10 @@ def gram_fused(X: jax.Array, W: jax.Array, b: jax.Array, T: jax.Array, *,
     X: (m, N, d_in) or (N, d_in) raw (backbone) features; W: (d_in, L),
     b: (L,) — the frozen ELM hidden layer ``H = act(X W + b)``; T matches
     X's leading shape with trailing D.  Returns (G, R) exactly like
-    ``gram_batched`` on the materialized H — bitwise-identical in fp32
-    (asserted in tests), because the kernel applies the same activation to
-    the same unpadded-d_in contraction and masks padding to exact zero.
+    ``gram_batched`` on the materialized H — bitwise-identical in fp32 to
+    an H built from the same (block_n, block_l) tile products (asserted in
+    tests), because the kernel applies the same activation to the same
+    unpadded-d_in contraction and masks padding to exact zero.
 
     ``precision="bf16"`` rounds the hidden tiles (and T) to bf16 before the
     MXU contraction, matching the materialized bf16 stream; int8 is not
@@ -300,7 +301,7 @@ def gram_fused(X: jax.Array, W: jax.Array, b: jax.Array, T: jax.Array, *,
     G, R = gram_pallas_fused(
         Xp, Wp, bp, Tp, n_true=N, l_true=L, activation=activation,
         block_l=block_l, block_n=block_n, compute_dtype=compute_dtype,
-        interpret=not _on_tpu(),
+        interpret=backend.interpret(),
     )
     G, R = G[:, :L, :L], R[:, :L]
     return (G, R) if batched else (G[0], R[0])

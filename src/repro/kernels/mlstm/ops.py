@@ -12,12 +12,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.mlstm.kernel import mlstm_pallas
 from repro.kernels.mlstm.ref import mlstm_sequential_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "force_ref"))
@@ -37,5 +34,5 @@ def mlstm_chunkwise(q, k, v, log_f, i_gate, *, chunk: int = 64,
         log_f = jnp.pad(log_f, zpad3)
         i_gate = jnp.pad(i_gate, zpad3, constant_values=-1e30)
     out = mlstm_pallas(q, k, v, log_f, i_gate, chunk=chunk,
-                       interpret=not _on_tpu())
+                       interpret=backend.interpret())
     return out[:, :, :S]

@@ -7,12 +7,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.rglru.kernel import rglru_pallas
 from repro.kernels.rglru.ref import rglru_scan_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -35,6 +32,7 @@ def rglru_scan(log_a, b, h0, *, block_s: int = 256, block_d: int = 512,
     bb = jnp.pad(b, ((0, 0), (0, pad_s), (0, pad_d)))
     h = jnp.pad(h0, ((0, 0), (0, pad_d)))
     out = rglru_pallas(
-        la, bb, h, block_s=block_s, block_d=block_d, interpret=not _on_tpu()
+        la, bb, h, block_s=block_s, block_d=block_d,
+        interpret=backend.interpret(),
     )
     return out[:, :S, :D]

@@ -7,12 +7,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend
 from repro.kernels.swa.kernel import swa_pallas
 from repro.kernels.swa.ref import swa_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -38,6 +35,6 @@ def swa_attention(q, k, v, *, window: int, block_q: int = 256,
         qp, kp, vp = q, k, v
     out = swa_pallas(
         qp, kp, vp, window=window, block_q=block_q,
-        block_k=min(block_k, S + pad), interpret=not _on_tpu(),
+        block_k=min(block_k, S + pad), interpret=backend.interpret(),
     )
     return out[:, :, :S]

@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init
 from repro.models.sharding import MODEL_AXIS, maybe_shard
@@ -190,9 +189,10 @@ def moe_ffn_shardmap(params, cfg: ModelConfig, x: jax.Array):
     # d / F over model, weights replicated across data inside the region.
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     bspec = batch_axes if batch_axes else None
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
+        check_vma=False,
         in_specs=(P(bspec, None, None),            # x full-d per shard
                   P(None, None, MODEL_AXIS),       # w_gate F-sharded
                   P(None, None, MODEL_AXIS),       # w_up F-sharded

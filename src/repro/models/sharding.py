@@ -15,14 +15,12 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import get_abstract_mesh
-
 BATCH_AXES = ("pod", "data")
 MODEL_AXIS = "model"
 
 
 def _active_mesh():
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh

@@ -289,10 +289,54 @@ def test_fused_activations_registry_matches_elm():
         assert KERNEL_ACTS[name] is ELM_ACTS[name], name
 
 
-@pytest.mark.parametrize("m,N,d_in,L", [
+def _tiled_hidden(X, fmap, block_n, block_l):
+    """The materialized hidden layer built tile by tile, each tile the same
+    (block_n, d_in) x (d_in, block_l) product the fused kernel computes.
+    XLA's CPU GEMM picks its blocking from the matrix size, so one tile of
+    a whole-matrix ``X @ W`` can differ from the tile product in the last
+    bits; building H from the kernel's own tile shapes keeps the bitwise
+    comparison about the kernel (grid, masks, accumulation order)."""
+    from repro.core.elm import ACTIVATIONS
+    from repro.kernels.gram.ops import resolve_block_n
+
+    m, N, _ = X.shape
+    L = fmap.W.shape[1]
+    bn = resolve_block_n(N, block_n)
+    Xp = jnp.pad(X, ((0, 0), (0, (-N) % bn), (0, 0)))
+    Wp = jnp.pad(fmap.W, ((0, 0), (0, (-L) % block_l)))
+    bp = jnp.pad(fmap.b, (0, (-L) % block_l))
+    act = ACTIVATIONS[fmap.activation]
+    tile = jax.jit(lambda x, w, b: act(jax.lax.dot_general(
+        x, w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) + b))
+    H = jnp.stack([
+        jnp.concatenate([
+            jnp.concatenate([
+                tile(Xp[a, n:n + bn], Wp[:, c:c + block_l],
+                     bp[c:c + block_l])
+                for c in range(0, Wp.shape[1], block_l)], axis=1)
+            for n in range(0, Xp.shape[1], bn)], axis=0)
+        for a in range(m)])
+    return H[:, :N, :L]
+
+
+_FUSED_SHAPES = [
     (2, 64, 16, 32), (1, 5, 3, 16), (2, 33, 8, 40),
     (1, 100, 36, 70), (2, 7, 11, 200),
-])
+]
+
+
+def _fused_case(m, N, d_in, L, activation):
+    from repro.core.elm import make_feature_map
+
+    kx, kf, kt = jax.random.split(jax.random.PRNGKey(m * N + d_in + L), 3)
+    X = jax.random.normal(kx, (m, N, d_in)) / jnp.sqrt(max(d_in, 1))
+    fmap = make_feature_map(kf, d_in, L, activation=activation)
+    T = jax.random.normal(kt, (m, N, 4))
+    return X, fmap, T
+
+
+@pytest.mark.parametrize("m,N,d_in,L", _FUSED_SHAPES)
 @pytest.mark.parametrize("activation", ["sigmoid", "tanh"])
 def test_gram_fused_bitwise_vs_materialized_pallas(m, N, d_in, L, activation):
     """The fused kernel must agree BITWISE (tol 0.0) with the materialized
@@ -300,14 +344,29 @@ def test_gram_fused_bitwise_vs_materialized_pallas(m, N, d_in, L, activation):
     accumulation order, with the hidden layer computed in-kernel instead of
     streamed.  Ragged N and L exercise the padded-grid masking: act(0) != 0
     for sigmoid, so any unmasked padding row/column poisons G."""
-    from repro.core.elm import make_feature_map
     from repro.kernels.gram.ops import gram_fused
 
-    kx, kf, kt = jax.random.split(jax.random.PRNGKey(m * N + d_in + L), 3)
-    X = jax.random.normal(kx, (m, N, d_in)) / jnp.sqrt(max(d_in, 1))
-    fmap = make_feature_map(kf, d_in, L, activation=activation)
-    T = jax.random.normal(kt, (m, N, 4))
+    X, fmap, T = _fused_case(m, N, d_in, L, activation)
     Gm, Rm = gram_batched(fmap(X), T, block_l=32, block_n=32)
+    Gf, Rf = gram_fused(X, fmap.W, fmap.b, T, activation=activation,
+                        block_l=32, block_n=32)
+    np.testing.assert_array_equal(np.asarray(Gf), np.asarray(Gm))
+    np.testing.assert_array_equal(np.asarray(Rf), np.asarray(Rm))
+
+
+@pytest.mark.parametrize("m,N,d_in,L", _FUSED_SHAPES)
+@pytest.mark.parametrize("activation", ["sigmoid", "tanh"])
+def test_gram_fused_bitwise_vs_tile_built_pallas(m, N, d_in, L, activation):
+    """The fused kernel against the materialized triangular kernel fed an H
+    built from the kernel's own (block_n, d_in) x (d_in, block_l) tile
+    products: BITWISE, whatever GEMM blocking the backend picks for a
+    whole-matrix ``X @ W`` — this isolates the kernel's grid, masks and
+    accumulation order from the hidden layer's rounding."""
+    from repro.kernels.gram.ops import gram_fused
+
+    X, fmap, T = _fused_case(m, N, d_in, L, activation)
+    Gm, Rm = gram_batched(_tiled_hidden(X, fmap, 32, 32), T, block_l=32,
+                          block_n=32)
     Gf, Rf = gram_fused(X, fmap.W, fmap.b, T, activation=activation,
                         block_l=32, block_n=32)
     np.testing.assert_array_equal(np.asarray(Gf), np.asarray(Gm))
@@ -350,6 +409,17 @@ def test_gram_fused_2d_matches_oracle():
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(Rf), np.asarray(Ro), rtol=1e-5,
                                atol=1e-5)
+
+
+def test_kernel_backend_decides_on_cpu():
+    """On the CPU kernels are interpreted and the stats producers default
+    to the jnp reference; ``use_pallas=True`` asks for the kernel."""
+    from repro.kernels import backend
+
+    assert backend.interpret() is True
+    assert backend.stats_use_pallas(None) is False
+    assert backend.stats_use_pallas(False) is False
+    assert backend.stats_use_pallas(True) is True
 
 
 def test_gram_fused_rejects_int8():
