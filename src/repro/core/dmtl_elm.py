@@ -191,8 +191,10 @@ def fit(
     Observability (``repro.obs``): ``telemetry=True`` sets
     ``cfg.telemetry`` — the per-iteration comm/aggregator counters ride
     the diagnostics dict (see the engine docstring's "Telemetry
-    extension"); ``trace_dir=`` activates host-side span tracing around
-    the stats pass, runner compile, and every segment, then writes
+    extension"); ``trace_dir=`` activates span tracing (a blocking
+    ``obs.Tracer``, with its compile counter) around the stats pass, the
+    runner build, each host read of the iteration counter and every
+    segment, then writes
     ``trace.json`` (Chrome trace format — load it in Perfetto),
     ``spans.jsonl``, and a run report (``report.md`` / ``report.json``)
     under that directory; ``health=`` (``True`` or a

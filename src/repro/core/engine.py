@@ -783,25 +783,31 @@ def agent_update(
     rho, mu1 = cfg.rho, cfg.mu1
     p_t = msgs.tau - rho * msgs.deg if cfg.prox == "prox_linear" else msgs.tau
 
-    M = mm(A, A.T)                                         # (r, r)
-    rhs = mm(stats.R, A.T) + rho * msgs.neigh_sum - msgs.ct_lam + p_t * U
-    if cfg.first_order:
-        # eq. (23): prox-linear collapses the solve to a scaled gradient step
-        grad_f = mm(mm(stats.G, U), M)
-        U_new = (rhs - grad_f - (mu1 / m_total) * U) / (rho * msgs.deg + p_t)
-    else:
-        if cfg.u_solver not in U_SOLVERS:
-            raise ValueError(
-                f"unknown u_solver {cfg.u_solver!r}; registered: "
-                f"{sorted(U_SOLVERS)}"
-            )
-        c_t = mu1 / m_total + rho * msgs.deg + p_t
-        U_new = U_SOLVERS[cfg.u_solver](stats.G, M, rhs, c_t, precomp)
+    # named scopes are trace-time metadata: they name the ops in the
+    # profiler's trace and change no value
+    with jax.named_scope("u_solve"):
+        M = mm(A, A.T)                                     # (r, r)
+        rhs = mm(stats.R, A.T) + rho * msgs.neigh_sum - msgs.ct_lam + p_t * U
+        if cfg.first_order:
+            # eq. (23): prox-linear collapses the solve to a scaled
+            # gradient step
+            grad_f = mm(mm(stats.G, U), M)
+            U_new = ((rhs - grad_f - (mu1 / m_total) * U)
+                     / (rho * msgs.deg + p_t))
+        else:
+            if cfg.u_solver not in U_SOLVERS:
+                raise ValueError(
+                    f"unknown u_solver {cfg.u_solver!r}; registered: "
+                    f"{sorted(U_SOLVERS)}"
+                )
+            c_t = mu1 / m_total + rho * msgs.deg + p_t
+            U_new = U_SOLVERS[cfg.u_solver](stats.G, M, rhs, c_t, precomp)
 
     # A update (eq. 21), purely local, on the fresh U
-    Ga = mm(mm(U_new.T, stats.G), U_new)
-    Ga = Ga + (msgs.zeta + cfg.mu2) * jnp.eye(cfg.r, dtype=U.dtype)
-    A_new = jnp.linalg.solve(Ga, mm(U_new.T, stats.R) + msgs.zeta * A)
+    with jax.named_scope("a_update"):
+        Ga = mm(mm(U_new.T, stats.G), U_new)
+        Ga = Ga + (msgs.zeta + cfg.mu2) * jnp.eye(cfg.r, dtype=U.dtype)
+        A_new = jnp.linalg.solve(Ga, mm(U_new.T, stats.R) + msgs.zeta * A)
     return U_new, A_new
 
 
@@ -1139,7 +1145,8 @@ class Runner:
         n = int(n_iters)
         if n < 0:
             raise ValueError(f"n_iters must be >= 0, got {n_iters}")
-        done = int(jax.device_get(state.k))
+        with obs_trace.span("sync"):
+            done = int(jax.device_get(state.k))
         if done + n > self.cfg.iters:
             raise ValueError(
                 f"segment [{done}, {done + n}) runs past cfg.iters="
@@ -1159,7 +1166,8 @@ class Runner:
         """Drive to ``cfg.iters`` from ``state`` (or a fresh init_state)."""
         if state is None:
             state = self.init_state()
-        done = int(jax.device_get(state.k))
+        with obs_trace.span("sync"):
+            done = int(jax.device_get(state.k))
         if done > self.cfg.iters:
             raise ValueError(
                 f"state is at iteration {done}, past cfg.iters="
@@ -1181,13 +1189,15 @@ def _make_dense_runner(
 
     def step(state, _):
         U, A, lam = state
-        neigh = es.neighbor_sum(U)                     # sum of neighbor U^k
-        ct_lam = es.ct_transpose(lam)                  # C_t^T lambda^k
+        with jax.named_scope("exchange"):
+            neigh = es.neighbor_sum(U)                 # sum of neighbor U^k
+            ct_lam = es.ct_transpose(lam)              # C_t^T lambda^k
         msgs = NeighborMsgs(neigh, ct_lam, es.deg, es.tau_t, es.zeta_t)
         U_new, A_new = es.body(stats, AgentState(U, A, None), msgs, es.precomp)
-        resid_old = es.edge_diff(U)
-        resid_new = es.edge_diff(U_new)
-        lam_new, gamma, primal = dual_step(lam, resid_old, resid_new, cfg)
+        with jax.named_scope("exchange"):
+            resid_old = es.edge_diff(U)
+            resid_new = es.edge_diff(U_new)
+            lam_new, gamma, primal = dual_step(lam, resid_old, resid_new, cfg)
         diag = _iteration_diag(
             stats, cfg, U_new, A_new, lam_new, resid_new, gamma, primal
         )
@@ -1794,45 +1804,48 @@ def ring_iteration(
     zeta_t = jnp.asarray(cfg.zeta, dtype)
 
     # --- gather neighbor subspaces and incoming edge duals --------------
-    robust_agg = resolve_aggregator(cfg)
-    neigh = jnp.zeros_like(U)
-    ct_lam = jnp.zeros_like(U)
-    views = []
-    u_next_old = []
-    own_edge = []
-    for ax_i, (ax, n_ax) in enumerate(zip(agent_axes, ax_sizes)):
-        u_next = _ring_recv_from_next(U, ax)            # U_{t+1}^k
-        lam_prev = _ring_recv_from_prev(lam[ax_i], ax)  # dual of edge (t-1, t)
-        if n_ax == 2:
-            # single edge: the one neighbor arrives on both permutes —
-            # count it once, and only agent 0 owns the edge dual
-            neigh = neigh + u_next
-            views.append(u_next)
-            own = (jax.lax.axis_index(ax) == 0).astype(dtype)
-        else:
-            u_prev = _ring_recv_from_prev(U, ax)        # U_{t-1}^k
-            neigh = neigh + u_next + u_prev
-            views.extend((u_next, u_prev))
-            own = jnp.asarray(1.0, dtype)
-        # C_t^T lambda: +lam on own (s-side) edge, -lam on incoming (e-side).
-        ct_lam = ct_lam + lam[ax_i] - lam_prev
-        u_next_old.append(u_next)
-        own_edge.append(own)
-    if robust_agg is not None:
-        # the shared aggregator contract (repro.core.exchange): received
-        # views + own U as candidates, all-ones mask (every ring neighbor
-        # is live), center rescaled back to the degree-weighted sum
-        neigh = exchange.stack_ring_candidates(views, U, deg, robust_agg,
-                                               dtype)
-    agg_rejected = jnp.zeros((), dtype)
-    if cfg.telemetry and robust_agg is not None:
-        # neigh = deg * agg(V, Mv) above, so neigh/deg is the exact robust
-        # center the aggregation used
-        V = jnp.stack(list(views) + [U], axis=0)
-        Mv = jnp.ones((V.shape[0],), dtype)
-        agg_rejected = jnp.sum(
-            exchange.aggregator_audit(V, Mv, neigh / deg)
-        )
+    with jax.named_scope("exchange"):
+        robust_agg = resolve_aggregator(cfg)
+        neigh = jnp.zeros_like(U)
+        ct_lam = jnp.zeros_like(U)
+        views = []
+        u_next_old = []
+        own_edge = []
+        for ax_i, (ax, n_ax) in enumerate(zip(agent_axes, ax_sizes)):
+            u_next = _ring_recv_from_next(U, ax)            # U_{t+1}^k
+            # the dual of edge (t-1, t)
+            lam_prev = _ring_recv_from_prev(lam[ax_i], ax)
+            if n_ax == 2:
+                # single edge: the one neighbor arrives on both permutes —
+                # count it once, and only agent 0 owns the edge dual
+                neigh = neigh + u_next
+                views.append(u_next)
+                own = (jax.lax.axis_index(ax) == 0).astype(dtype)
+            else:
+                u_prev = _ring_recv_from_prev(U, ax)        # U_{t-1}^k
+                neigh = neigh + u_next + u_prev
+                views.extend((u_next, u_prev))
+                own = jnp.asarray(1.0, dtype)
+            # C_t^T lambda: +lam on own (s-side) edge, -lam on incoming
+            # (e-side).
+            ct_lam = ct_lam + lam[ax_i] - lam_prev
+            u_next_old.append(u_next)
+            own_edge.append(own)
+        if robust_agg is not None:
+            # the shared aggregator contract (repro.core.exchange): received
+            # views + own U as candidates, all-ones mask (every ring neighbor
+            # is live), center rescaled back to the degree-weighted sum
+            neigh = exchange.stack_ring_candidates(views, U, deg, robust_agg,
+                                                   dtype)
+        agg_rejected = jnp.zeros((), dtype)
+        if cfg.telemetry and robust_agg is not None:
+            # neigh = deg * agg(V, Mv) above, so neigh/deg is the exact robust
+            # center the aggregation used
+            V = jnp.stack(list(views) + [U], axis=0)
+            Mv = jnp.ones((V.shape[0],), dtype)
+            agg_rejected = jnp.sum(
+                exchange.aggregator_audit(V, Mv, neigh / deg)
+            )
 
     # --- the shared per-agent body ---------------------------------------
     msgs = NeighborMsgs(neigh, ct_lam, deg, tau_t, zeta_t)
@@ -1844,33 +1857,36 @@ def ring_iteration(
     # --- shared dual step on the owned edge (t, t+1) per axis ------------
     # Per-edge diagnostics are accumulated over OWNED edges only (masked by
     # own_edge), so a plain cross-shard sum outside counts each edge once.
-    lam_new = []
-    primal_sq = jnp.zeros((), dtype)
-    gamma_sum = jnp.zeros((), dtype)
-    gamma_min = jnp.asarray(jnp.inf, dtype)
-    lag_pen = jnp.zeros((), dtype)
-    resid_max = jnp.zeros((), dtype)
-    for ax_i, ax in enumerate(agent_axes):
-        u_next_new = _ring_recv_from_next(U_new, ax)
-        resid_new = U_new - u_next_new                  # \hat C_i U^{k+1}
-        resid_old = U - u_next_old[ax_i]                # \hat C_i U^k
-        lam_ax, gamma, primal = dual_step(lam[ax_i], resid_old, resid_new, cfg)
-        own = own_edge[ax_i]
-        lam_new.append(own * lam_ax)
-        primal_sq = primal_sq + own * primal
-        gamma_sum = gamma_sum + own * gamma
-        gamma_min = jnp.minimum(
-            gamma_min, jnp.where(own > 0, gamma, jnp.inf)
-        )
-        lag_pen = lag_pen + own * (
-            jnp.sum(lam_ax * resid_new) + 0.5 * cfg.rho * jnp.sum(resid_new**2)
-        )
-        if cfg.telemetry:
-            resid_max = jnp.maximum(
-                resid_max,
-                jnp.where(own > 0, jnp.max(jnp.abs(resid_new)), 0.0),
+    with jax.named_scope("exchange"):
+        lam_new = []
+        primal_sq = jnp.zeros((), dtype)
+        gamma_sum = jnp.zeros((), dtype)
+        gamma_min = jnp.asarray(jnp.inf, dtype)
+        lag_pen = jnp.zeros((), dtype)
+        resid_max = jnp.zeros((), dtype)
+        for ax_i, ax in enumerate(agent_axes):
+            u_next_new = _ring_recv_from_next(U_new, ax)
+            resid_new = U_new - u_next_new                  # \hat C_i U^{k+1}
+            resid_old = U - u_next_old[ax_i]                # \hat C_i U^k
+            lam_ax, gamma, primal = dual_step(
+                lam[ax_i], resid_old, resid_new, cfg)
+            own = own_edge[ax_i]
+            lam_new.append(own * lam_ax)
+            primal_sq = primal_sq + own * primal
+            gamma_sum = gamma_sum + own * gamma
+            gamma_min = jnp.minimum(
+                gamma_min, jnp.where(own > 0, gamma, jnp.inf)
             )
-    lam_new = jnp.stack(lam_new)
+            lag_pen = lag_pen + own * (
+                jnp.sum(lam_ax * resid_new)
+                + 0.5 * cfg.rho * jnp.sum(resid_new**2)
+            )
+            if cfg.telemetry:
+                resid_max = jnp.maximum(
+                    resid_max,
+                    jnp.where(own > 0, jnp.max(jnp.abs(resid_new)), 0.0),
+                )
+        lam_new = jnp.stack(lam_new)
 
     diag = {
         "primal_sq": primal_sq,
@@ -2219,24 +2235,30 @@ def _make_sharded_graph_runner(
         def step(carry, _):
             U, A, lam = carry
             U_start = U
-            # C_t^T lambda: + the duals this shard owns (unowned slots stay
-            # zero), - every incoming dual, shipped source->dest per round
-            ct_lam = sgx.ship_ct_lam(lam, slots, own)
-            u_start_nb = sgx.exchange(U_start)  # also resid_old for duals
-            nb = u_start_nb
-            agg_rejected = jnp.zeros((), dtype)
-            if cfg.telemetry and robust_agg is not None:
-                # reduce_views returns deg_t * agg(V, Mv), so dividing the
-                # degree back out recovers the exact robust center audited
-                neigh0 = sgx.reduce_views(u_start_nb, U_start, deg_t, rmask)
-                agg_rejected = sgx.audit_views(
-                    u_start_nb, U_start, rmask,
-                    neigh0 / jnp.maximum(deg_t, 1.0),
-                )
+            with jax.named_scope("exchange"):
+                # C_t^T lambda: + the duals this shard owns (unowned slots
+                # stay zero), - every incoming dual, shipped source->dest
+                # per round
+                ct_lam = sgx.ship_ct_lam(lam, slots, own)
+                u_start_nb = sgx.exchange(U_start)  # also resid_old for duals
+                nb = u_start_nb
+                agg_rejected = jnp.zeros((), dtype)
+                if cfg.telemetry and robust_agg is not None:
+                    # reduce_views returns deg_t * agg(V, Mv), so dividing
+                    # the degree back out recovers the exact robust center
+                    # audited
+                    neigh0 = sgx.reduce_views(u_start_nb, U_start, deg_t,
+                                              rmask)
+                    agg_rejected = sgx.audit_views(
+                        u_start_nb, U_start, rmask,
+                        neigh0 / jnp.maximum(deg_t, 1.0),
+                    )
             for p in range(n_phases):
-                if p > 0:
-                    nb = sgx.exchange(U)        # live U: Gauss-Seidel phases
-                neigh = sgx.reduce_views(nb, U, deg_t, rmask)
+                with jax.named_scope("exchange"):
+                    if p > 0:
+                        # live U: Gauss-Seidel phases
+                        nb = sgx.exchange(U)
+                    neigh = sgx.reduce_views(nb, U, deg_t, rmask)
                 msgs = NeighborMsgs(neigh, ct_lam, deg_t, tau_t, zeta_t)
                 U_upd, A_upd = agent_update(
                     stats_t, AgentState(U, A, lam), msgs, cfg,
@@ -2248,35 +2270,37 @@ def _make_sharded_graph_runner(
 
             # dual step on owned edges; diagnostics masked to owned edges so
             # the host-side cross-shard sum counts each edge once
-            u_new_nb = sgx.exchange(U)
-            primal_sq = jnp.zeros((), dtype)
-            gamma_sum = jnp.zeros((), dtype)
-            gamma_min = jnp.asarray(jnp.inf, dtype)
-            lag_pen = jnp.zeros((), dtype)
-            resid_max = jnp.zeros((), dtype)
-            for rr in range(n_rounds):
-                resid_new = U - u_new_nb[rr]            # C_i U^{k+1} on src
-                resid_old = U_start - u_start_nb[rr]    # C_i U^k on src
-                lam_rr = lam[slots[rr]]
-                lam_upd, gamma, primal = dual_step(
-                    lam_rr, resid_old, resid_new, cfg
-                )
-                o = own[rr]
-                lam = lam.at[slots[rr]].set(jnp.where(o > 0, lam_upd, lam_rr))
-                primal_sq = primal_sq + o * primal
-                gamma_sum = gamma_sum + o * gamma
-                gamma_min = jnp.minimum(
-                    gamma_min, jnp.where(o > 0, gamma, jnp.inf)
-                )
-                lag_pen = lag_pen + o * (
-                    jnp.sum(lam_upd * resid_new)
-                    + 0.5 * cfg.rho * jnp.sum(resid_new**2)
-                )
-                if cfg.telemetry:
-                    resid_max = jnp.maximum(
-                        resid_max,
-                        jnp.where(o > 0, jnp.max(jnp.abs(resid_new)), 0.0),
+            with jax.named_scope("exchange"):
+                u_new_nb = sgx.exchange(U)
+                primal_sq = jnp.zeros((), dtype)
+                gamma_sum = jnp.zeros((), dtype)
+                gamma_min = jnp.asarray(jnp.inf, dtype)
+                lag_pen = jnp.zeros((), dtype)
+                resid_max = jnp.zeros((), dtype)
+                for rr in range(n_rounds):
+                    resid_new = U - u_new_nb[rr]        # C_i U^{k+1} on src
+                    resid_old = U_start - u_start_nb[rr]  # C_i U^k on src
+                    lam_rr = lam[slots[rr]]
+                    lam_upd, gamma, primal = dual_step(
+                        lam_rr, resid_old, resid_new, cfg
                     )
+                    o = own[rr]
+                    lam = lam.at[slots[rr]].set(
+                        jnp.where(o > 0, lam_upd, lam_rr))
+                    primal_sq = primal_sq + o * primal
+                    gamma_sum = gamma_sum + o * gamma
+                    gamma_min = jnp.minimum(
+                        gamma_min, jnp.where(o > 0, gamma, jnp.inf)
+                    )
+                    lag_pen = lag_pen + o * (
+                        jnp.sum(lam_upd * resid_new)
+                        + 0.5 * cfg.rho * jnp.sum(resid_new**2)
+                    )
+                    if cfg.telemetry:
+                        resid_max = jnp.maximum(
+                            resid_max,
+                            jnp.where(o > 0, jnp.max(jnp.abs(resid_new)), 0.0),
+                        )
             diag = {
                 "obj": _local_objective(stats_t, U, A, cfg, m),
                 "lag_pen": lag_pen,
@@ -2526,7 +2550,8 @@ def _make_sharded_graph_runner(
                 diags["comm_floats"] = jnp.full((n,), float(model), dtype)
             return state._replace(U=U, A=A, lam=lam, k=state.k + n), diags
 
-        k0 = int(jax.device_get(state.k))
+        with obs_trace.span("sync"):
+            k0 = int(jax.device_get(state.k))
         if k0 + n > cfg.iters:
             raise ValueError(
                 f"segment [{k0}, {k0 + n}) runs past the tape "
@@ -2660,20 +2685,14 @@ def make_runner(
     """
     if cfg is None:
         raise ValueError("make_runner requires a ConsensusConfig")
-    tr = obs_trace.current()
-    if tr is not None:
-        with tr.span("compile", executor=executor):
-            return _dispatch_runner(
-                stats, g, cfg, executor=executor, mesh=mesh,
-                agent_axes=agent_axes, schedule=schedule,
-                staleness=staleness, order=order, tape=tape,
-                aged_duals=aged_duals,
-            )
-    return _dispatch_runner(
-        stats, g, cfg, executor=executor, mesh=mesh, agent_axes=agent_axes,
-        schedule=schedule, staleness=staleness, order=order, tape=tape,
-        aged_duals=aged_duals,
-    )
+    # closures, edge set-up and the U-solver's hoisted set-up (the eigh is
+    # enqueued here, not waited for); the scan compiles in run_segment
+    with obs_trace.span("runner_build", executor=executor):
+        return _dispatch_runner(
+            stats, g, cfg, executor=executor, mesh=mesh,
+            agent_axes=agent_axes, schedule=schedule, staleness=staleness,
+            order=order, tape=tape, aged_duals=aged_duals,
+        )
 
 
 def _dispatch_runner(

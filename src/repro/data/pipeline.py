@@ -22,6 +22,8 @@ from typing import Iterable, Iterator, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace as obs_trace
+
 
 @dataclasses.dataclass(frozen=True)
 class DataConfig:
@@ -115,52 +117,73 @@ def stream_sufficient_stats(
         L = feature_map.L if producer == "fused" else H.shape[-1]
         return init_stats(H.shape[0], L, T.shape[-1], jnp.float32)
 
-    comp = None
-    for H, T in feature_batches:
-        if stats is None:
-            stats = empty_stats(H, T)
-        if not compensated:
+    # one span over the stream; inside it, the time each pull of the next
+    # batch waited for its producer
+    with obs_trace.span("stats", producer=producer,
+                        precision=precision) as span_args:
+        comp = None
+        batches = 0
+        for H, T in _pulls(feature_batches):
+            batches += 1
+            if stats is None:
+                stats = empty_stats(H, T)
+            if not compensated:
+                if chunk is not None and H.shape[1] > chunk:
+                    stats = accumulate_stats_chunked(stats, H, T, chunk,
+                                                     use_pallas=use_pallas,
+                                                     precision=precision,
+                                                     producer=producer,
+                                                     feature_map=feature_map)
+                else:
+                    stats = accumulate_stats(stats, H, T,
+                                             use_pallas=use_pallas,
+                                             precision=precision,
+                                             producer=producer,
+                                             feature_map=feature_map)
+                continue
+            # Compensated: reduce THIS batch alone from zero (its internal
+            # sums are same-magnitude, so the plain/chunked fold is fine),
+            # then fold it into the running totals through Kahan adds whose
+            # compensation persists across batches.
+            zero = empty_stats(H, T)
             if chunk is not None and H.shape[1] > chunk:
-                stats = accumulate_stats_chunked(stats, H, T, chunk,
-                                                 use_pallas=use_pallas,
-                                                 precision=precision,
-                                                 producer=producer,
-                                                 feature_map=feature_map)
+                b = accumulate_stats_chunked(zero, H, T, chunk,
+                                             use_pallas=use_pallas,
+                                             precision=precision,
+                                             compensated=True,
+                                             producer=producer,
+                                             feature_map=feature_map)
             else:
-                stats = accumulate_stats(stats, H, T, use_pallas=use_pallas,
-                                         precision=precision,
-                                         producer=producer,
-                                         feature_map=feature_map)
-            continue
-        # Compensated: reduce THIS batch alone from zero (its internal sums
-        # are same-magnitude, so the plain/chunked fold is fine), then fold
-        # it into the running totals through Kahan adds whose compensation
-        # persists across batches.
-        zero = empty_stats(H, T)
-        if chunk is not None and H.shape[1] > chunk:
-            b = accumulate_stats_chunked(zero, H, T, chunk,
-                                         use_pallas=use_pallas,
-                                         precision=precision,
-                                         compensated=True,
-                                         producer=producer,
-                                         feature_map=feature_map)
-        else:
-            b = accumulate_stats(zero, H, T, use_pallas=use_pallas,
-                                 precision=precision, producer=producer,
-                                 feature_map=feature_map)
-        t2_run = jnp.broadcast_to(
-            jnp.asarray(stats.t2, jnp.float32), b.t2.shape)
-        if comp is None:
-            comp = (jnp.zeros_like(stats.G), jnp.zeros_like(stats.R),
-                    jnp.zeros_like(t2_run))
-        G, cG = _kahan_add(stats.G, comp[0], b.G)
-        R, cR = _kahan_add(stats.R, comp[1], b.R)
-        t2, ct2 = _kahan_add(t2_run, comp[2], b.t2)
-        comp = (cG, cR, ct2)
-        stats = SufficientStats(G=G, R=R, n=stats.n + b.n, t2=t2)
+                b = accumulate_stats(zero, H, T, use_pallas=use_pallas,
+                                     precision=precision, producer=producer,
+                                     feature_map=feature_map)
+            t2_run = jnp.broadcast_to(
+                jnp.asarray(stats.t2, jnp.float32), b.t2.shape)
+            if comp is None:
+                comp = (jnp.zeros_like(stats.G), jnp.zeros_like(stats.R),
+                        jnp.zeros_like(t2_run))
+            G, cG = _kahan_add(stats.G, comp[0], b.G)
+            R, cR = _kahan_add(stats.R, comp[1], b.R)
+            t2, ct2 = _kahan_add(t2_run, comp[2], b.t2)
+            comp = (cG, cR, ct2)
+            stats = SufficientStats(G=G, R=R, n=stats.n + b.n, t2=t2)
+        if span_args is not None:
+            span_args["batches"] = batches
     if stats is None:
         raise ValueError(
             "stream_sufficient_stats: empty feature stream and no initial "
             "stats — pass `stats=init_stats(...)` or a non-empty iterator"
         )
     return stats
+
+
+def _pulls(batches):
+    """The items of ``batches``, each pull inside a ``feed_wait`` span."""
+    it = iter(batches)
+    while True:
+        with obs_trace.span("feed_wait"):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item

@@ -22,15 +22,29 @@ _COUNTER_KEYS = ("msgs_delivered", "msgs_stale", "msgs_dropped",
 
 def _span_breakdown(spans) -> list[dict]:
     """Total wall time per span name, top-level spans only (depth 0), so
-    nested segment/snapshot time is not double counted."""
+    nested segment/snapshot time is not double counted.  ``compiles``
+    counts the compile events by kind (the tracer's compile counter) in those
+    spans and every span under them."""
+    spans = list(spans or [])
     totals: dict[str, dict] = {}
-    for s in spans or []:
+    for s in spans:
         if s.get("depth", 0) != 0:
             continue
-        row = totals.setdefault(s["name"], {"name": s["name"],
-                                            "count": 0, "total_us": 0.0})
+        row = totals.setdefault(s["name"], {"name": s["name"], "count": 0,
+                                            "total_us": 0.0, "compiles": {}})
         row["count"] += 1
         row["total_us"] += float(s["dur"])
+    by_id = {s["id"]: s for s in spans if "id" in s}
+    for s in spans:
+        if not s.get("compiles"):
+            continue
+        root = s
+        while root.get("parent") is not None:
+            root = by_id[root["parent"]]
+        counts = totals[root["name"]]["compiles"]
+        for kind, funs in s["compiles"].items():
+            counts[kind] = counts.get(kind, 0) + sum(
+                n for n, _ in funs.values())
     return sorted(totals.values(), key=lambda r: -r["total_us"])
 
 
@@ -85,10 +99,12 @@ def render(
         lines += [""]
     if data["time_breakdown"]:
         lines += ["## Time breakdown (top-level spans)", "",
-                  "| span | count | total ms |",
-                  "| --- | ---: | ---: |"]
+                  "| span | count | total ms | compile events |",
+                  "| --- | ---: | ---: | --- |"]
         lines += [
-            f"| {r['name']} | {r['count']} | {r['total_us'] / 1e3:.3f} |"
+            f"| {r['name']} | {r['count']} | {r['total_us'] / 1e3:.3f} | "
+            + ", ".join(f"{k} {n}" for k, n in sorted(r["compiles"].items()))
+            + " |"
             for r in data["time_breakdown"]
         ]
         lines += [""]

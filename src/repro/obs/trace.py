@@ -1,14 +1,28 @@
-"""Host-side span tracing for the consensus runtime.
+"""Span tracing for the consensus runtime, on the profiler's clock.
 
-One ``Tracer`` records named wall-clock spans (``compile`` / ``stats`` /
-``segment`` / ``snapshot`` / ``restore`` / bench-defined names) as flat
-dicts sharing one clock (``time.perf_counter`` — the same clock
-``timed`` uses, so bench timings and trace spans are directly
-comparable).  ``export`` writes two artifacts:
+One ``Tracer`` records named spans at the layer boundaries of a fit
+(``stats`` / ``feed_wait`` / ``runner_build`` / ``sync`` / ``segment`` /
+``snapshot`` / ``restore``) as flat dicts: start and duration in µs
+since the tracer was made (``time.perf_counter``), ``depth``, the span's
+own ``id`` (its opening order) and its ``parent``'s id (``None`` at the
+top).  While a tracer is installed each span also enters a
+``jax.profiler.TraceAnnotation`` named ``obs:<name>``, so inside a
+``jax.profiler`` trace every program span sits in the host plane on the
+same clock as the device ops, and an idle gap on the device can be put
+down to the span the host was in.
+
+``use(tracer)`` also registers ``jax.monitoring`` listeners for the
+compile events (jaxpr trace, lowering to MLIR, backend compile, and a
+persistent-cache read); each event adds a count and its seconds to the
+innermost open span, keyed by kind and the traced function's
+``fun_name`` (``span["compiles"]``).  The listeners go when ``use``
+exits.
+
+``export`` writes two artifacts:
 
   trace.json   Chrome trace event format (``ph: "X"`` complete events,
-               microsecond timestamps) — loadable in Perfetto or
-               chrome://tracing.
+               microsecond timestamps, ``parent`` and ``compiles`` in
+               ``args``) — loadable in Perfetto or chrome://tracing.
   spans.jsonl  one span per line for grepping / pandas.
 
 Activation is a dynamically-scoped global: ``with use(tracer): ...``
@@ -19,15 +33,15 @@ installs the tracer, and instrumented call sites do
 
 ``span(...)`` returns a shared ``contextlib.nullcontext()`` when no
 tracer is installed, so the OFF cost at every instrumentation point is a
-single function call and a global read — nothing is allocated and no
-clock is consulted.  This is the host-side half of the zero-overhead
-guarantee (the device-side half is the ``cfg.telemetry`` gate in
-``repro.core.engine``).
+single function call and a global read — nothing is allocated, no clock
+is consulted and no listener is registered.  This is the host-side half
+of the zero-overhead guarantee (the device-side half is the
+``cfg.telemetry`` gate in ``repro.core.engine``).
 
-Instrumented sites additionally ``jax.block_until_ready`` their outputs
-*inside* the span only when a tracer is active, so span durations
-reflect actual device work rather than dispatch time — again at zero
-cost when tracing is off.
+Instrumented sites that dispatch device work (the stats pass, every
+segment) additionally ``jax.block_until_ready`` their outputs *inside*
+the span when a tracer is installed, so span durations cover the device
+work rather than its enqueue — at zero cost when tracing is off.
 """
 
 from __future__ import annotations
@@ -55,30 +69,72 @@ def timed(fn, *args, repeats: int = 1, **kwargs):
     return out, dt
 
 
+PREFIX = "obs:"
+# jax.monitoring's compile events, by the kind a span's counter keys them
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+CACHE_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
 class Tracer:
     """Records spans relative to its construction time (µs)."""
 
     def __init__(self) -> None:
         self.spans: list[dict] = []
+        # compile events that arrived with no span open
+        self.compiles: dict[str, dict[str, list]] = {}
         self._t0 = _CLOCK()
-        self._depth = 0
+        self._open: list[dict] = []
+        self._next_id = 0
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
+        """Yields the span's ``args``: what the block adds to it lands in
+        the record (not in the annotation, whose arguments are fixed when
+        it opens)."""
+        rec = {
+            "name": name,
+            "id": self._next_id,
+            "parent": self._open[-1]["id"] if self._open else None,
+            "depth": len(self._open),
+            "args": args,
+        }
+        self._next_id += 1
+        self._open.append(rec)
         t0 = _CLOCK()
-        self._depth += 1
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(PREFIX + name, **args):
+                yield args
         finally:
-            self._depth -= 1
             t1 = _CLOCK()
-            self.spans.append({
-                "name": name,
-                "ts": (t0 - self._t0) * 1e6,
-                "dur": (t1 - t0) * 1e6,
-                "depth": self._depth,
-                "args": args,
-            })
+            self._open.pop()
+            rec["ts"] = (t0 - self._t0) * 1e6
+            rec["dur"] = (t1 - t0) * 1e6
+            self.spans.append(rec)
+
+    def _count_compile(self, kind: str, fun_name: str, seconds: float):
+        """Add one compile event to the innermost open span."""
+        if self._open:
+            counts = self._open[-1].setdefault("compiles", {})
+        else:
+            counts = self.compiles
+        row = counts.setdefault(kind, {}).setdefault(fun_name, [0, 0.0])
+        row[0] += 1
+        row[1] += seconds
+
+    def _on_time_span(self, event, start, end, **kwargs):
+        kind = COMPILE_EVENTS.get(event)
+        if kind is not None and _ACTIVE is self:
+            self._count_compile(kind, str(kwargs.get("fun_name", "")),
+                                end - start)
+
+    def _on_duration(self, event, duration, **kwargs):
+        if event == CACHE_EVENT and _ACTIVE is self:
+            self._count_compile("cache_read",
+                                str(kwargs.get("fun_name", "")), duration)
 
     def trace_events(self) -> list[dict]:
         """Chrome trace event format rows (complete ``ph: "X"`` events)."""
@@ -91,7 +147,9 @@ class Tracer:
                 "dur": s["dur"],
                 "pid": pid,
                 "tid": 0,
-                "args": s["args"],
+                "args": {**s["args"], "parent": s.get("parent"),
+                         **({"compiles": s["compiles"]}
+                            if "compiles" in s else {})},
             }
             for s in sorted(self.spans, key=lambda s: (s["ts"], -s["dur"]))
         ]
@@ -126,14 +184,25 @@ def current() -> Tracer | None:
 
 @contextlib.contextmanager
 def use(tracer: Tracer | None):
-    """Install ``tracer`` for the dynamic extent of the with-block."""
+    """Install ``tracer`` for the dynamic extent of the with-block, with
+    its compile-event listeners registered for the same extent."""
     global _ACTIVE
     prev = _ACTIVE
     _ACTIVE = tracer
+    if tracer is not None:
+        jax.monitoring.register_event_time_span_listener(
+            tracer._on_time_span)
+        jax.monitoring.register_event_duration_secs_listener(
+            tracer._on_duration)
     try:
         yield tracer
     finally:
         _ACTIVE = prev
+        if tracer is not None:
+            jax.monitoring.unregister_event_time_span_listener(
+                tracer._on_time_span)
+            jax.monitoring.unregister_event_duration_listener(
+                tracer._on_duration)
 
 
 def span(name: str, **args):

@@ -360,7 +360,7 @@ def test_fit_trace_dir_emits_valid_trace_and_report(tmp_path):
     assert rep["iterations"] == cfg.iters
     assert rep["comm"]["msgs_delivered_total"] == 2.0 * g.n_edges * cfg.iters
     span_names = {r["name"] for r in rep["time_breakdown"]}
-    assert {"stats", "compile", "segment"} <= span_names
+    assert {"stats", "runner_build", "segment"} <= span_names
     md = (tmp_path / "report.md").read_text()
     assert "# Run report" in md and "## Communication" in md
 
