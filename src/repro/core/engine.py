@@ -253,6 +253,12 @@ parity is true by construction; new topologies or async sweeps only need a
 new executor, never a new update body.  Iteration-invariant work (the
 eigendecomposition of G_t used by the ``sylvester`` solver) is hoisted out
 of the ADMM scan by ``hoist_precomp`` in every executor.
+
+The dense executor's segment program is built once per segment length,
+graph, config and argument shapes, and reused by every later runner:
+a refit of the same shapes neither traces nor lowers its scan
+(``segment_program_cache_info`` counts hits and misses; a traced
+``segment`` span records ``program="hit"`` or ``"miss"``).
 """
 
 from __future__ import annotations
@@ -971,6 +977,27 @@ class _EdgeSetup(NamedTuple):
     ex: "exchange.DenseExchange"
 
 
+def _agent_body(cfg: ConsensusConfig, m: int, precomp,
+                update: Callable) -> Callable:
+    """``update`` (``agent_update``) vmapped over the agent axis
+    (``precomp`` batched with the agents when there is one)."""
+
+    def one_agent(stats_t, state_t, msgs_t, precomp_t):
+        return update(
+            stats_t, state_t, msgs_t, cfg, m_total=m, precomp=precomp_t
+        )
+
+    return jax.vmap(
+        one_agent,
+        in_axes=(
+            0,
+            AgentState(0, 0, None),
+            0,
+            None if precomp is None else 0,
+        ),
+    )
+
+
 def _edge_setup(
     stats: SufficientStats, g: Graph, cfg: ConsensusConfig
 ) -> _EdgeSetup:
@@ -995,21 +1022,7 @@ def _edge_setup(
     edge_diff = ex.edge_diff
     neighbor_sum = ex.neighbor_sum
     ct_transpose = ex.ct_transpose
-
-    def one_agent(stats_t, state_t, msgs_t, precomp_t):
-        return agent_update(
-            stats_t, state_t, msgs_t, cfg, m_total=m, precomp=precomp_t
-        )
-
-    body = jax.vmap(
-        one_agent,
-        in_axes=(
-            0,
-            AgentState(0, 0, None),
-            0,
-            None if precomp is None else 0,
-        ),
-    )
+    body = _agent_body(cfg, m, precomp, agent_update)
 
     init = DenseState(
         U=jnp.ones((m, L, cfg.r), dtype=dtype),
@@ -1181,22 +1194,51 @@ class Runner:
 # --------------------------------------------------------------------------
 
 
-def _make_dense_runner(
-    stats: SufficientStats, g: Graph, cfg: ConsensusConfig,
-) -> Runner:
-    es = _edge_setup(stats, g, cfg)
-    stats = es.stats
+# The dense executor's segment program is built once per key and reused by
+# every later runner: a refit of the same shapes neither traces nor lowers.
+# The key is jit's own: the static arguments (segment length, graph, the
+# config less its array-valued ``tau`` and its ``iters``, the round body
+# and the registered U-solver it calls) and the shapes, dtypes and
+# shardings of the array arguments.  Every array of a fit is an argument,
+# so the program holds none of them.
+_SEGMENT_PROGRAMS = {"hits": 0, "misses": 0}
+
+
+def segment_program_cache_info() -> dict:
+    """Dense segment-program calls that reused a built program (``hits``)
+    and that traced a new one (``misses``), since the last clear."""
+    return dict(_SEGMENT_PROGRAMS)
+
+
+def segment_program_cache_clear() -> None:
+    """Drop every built dense segment program and zero the counts."""
+    _dense_scan.clear_cache()
+    _SEGMENT_PROGRAMS.update(hits=0, misses=0)
+
+
+def _program_config(cfg: ConsensusConfig) -> ConsensusConfig:
+    """The config as the segment program's key: ``tau`` (possibly a
+    per-agent array) reaches the program resolved, as ``tau_t``, and
+    ``iters`` bounds the runner, not one segment."""
+    return dataclasses.replace(cfg, tau=None, iters=0)
+
+
+def _dense_scan_fn(n, g, cfg, update, solver, stats, precomp, ex, tau_t,
+                   zeta_t, carry):
+    del solver  # a key only: a solver registered anew is a new program
+    _SEGMENT_PROGRAMS["misses"] += 1       # runs only when jit traces
+    body = _agent_body(cfg, stats.G.shape[0], precomp, update)
 
     def step(state, _):
         U, A, lam = state
         with jax.named_scope("exchange"):
-            neigh = es.neighbor_sum(U)                 # sum of neighbor U^k
-            ct_lam = es.ct_transpose(lam)              # C_t^T lambda^k
-        msgs = NeighborMsgs(neigh, ct_lam, es.deg, es.tau_t, es.zeta_t)
-        U_new, A_new = es.body(stats, AgentState(U, A, None), msgs, es.precomp)
+            neigh = ex.neighbor_sum(U)                 # sum of neighbor U^k
+            ct_lam = ex.ct_transpose(lam)              # C_t^T lambda^k
+        msgs = NeighborMsgs(neigh, ct_lam, ex.deg, tau_t, zeta_t)
+        U_new, A_new = body(stats, AgentState(U, A, None), msgs, precomp)
         with jax.named_scope("exchange"):
-            resid_old = es.edge_diff(U)
-            resid_new = es.edge_diff(U_new)
+            resid_old = ex.edge_diff(U)
+            resid_new = ex.edge_diff(U_new)
             lam_new, gamma, primal = dual_step(lam, resid_old, resid_new, cfg)
         diag = _iteration_diag(
             stats, cfg, U_new, A_new, lam_new, resid_new, gamma, primal
@@ -1209,10 +1251,33 @@ def _make_dense_runner(
             diag["msgs_stale"] = jnp.zeros((), dtype)
             diag["msgs_dropped"] = jnp.zeros((), dtype)
             diag["agg_rejected"] = (
-                es.ex.audit(U) if es.ex.agg is not None
+                ex.audit(U) if ex.agg is not None
                 else jnp.zeros((), dtype)
             )
         return DenseState(U_new, A_new, lam_new), diag
+
+    final, diags = jax.lax.scan(step, carry, None, length=n)
+    if cfg.telemetry:
+        model = modeled_floats_per_iter(
+            "dense", L=stats.G.shape[-1], r=cfg.r, n_edges=g.n_edges
+        )
+        diags["comm_floats"] = jnp.full((n,), float(model), stats.G.dtype)
+    return final, diags
+
+
+# named as the eager scan's program was (``jit(scan)``, module ``jit_scan``),
+# the name the compile counter and the device-trace readers find it by
+_dense_scan_fn.__name__ = _dense_scan_fn.__qualname__ = "scan"
+_dense_scan = jax.jit(_dense_scan_fn, static_argnums=(0, 1, 2, 3, 4))
+
+
+def _make_dense_runner(
+    stats: SufficientStats, g: Graph, cfg: ConsensusConfig,
+) -> Runner:
+    es = _edge_setup(stats, g, cfg)
+    static = (g, _program_config(cfg), agent_update,
+              U_SOLVERS.get(cfg.u_solver))
+    operands = (es.stats, es.precomp, es.ex, es.tau_t, es.zeta_t)
 
     def init_fn():
         return RunState(
@@ -1224,14 +1289,15 @@ def _make_dense_runner(
         # the scan carry is exactly the monolithic executor's DenseState —
         # the counter advances OUTSIDE the scan, so a segment boundary
         # cannot perturb the traced computation
-        final, diags = jax.lax.scan(
-            step, DenseState(state.U, state.A, state.lam), None, length=n
+        misses = _SEGMENT_PROGRAMS["misses"]
+        final, diags = _dense_scan(
+            n, *static, *operands, DenseState(state.U, state.A, state.lam)
         )
-        if cfg.telemetry:
-            model = modeled_floats_per_iter(
-                "dense", L=stats.G.shape[-1], r=cfg.r, n_edges=g.n_edges
-            )
-            diags["comm_floats"] = jnp.full((n,), float(model), stats.G.dtype)
+        if _SEGMENT_PROGRAMS["misses"] == misses:
+            _SEGMENT_PROGRAMS["hits"] += 1
+            obs_trace.annotate(program="hit")
+        else:
+            obs_trace.annotate(program="miss")
         return state._replace(
             U=final.U, A=final.A, lam=final.lam, k=state.k + n
         ), diags

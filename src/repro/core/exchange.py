@@ -164,6 +164,7 @@ def aggregator_audit(V, M, center):
     return rej.astype(V.dtype)
 
 
+@jax.tree_util.register_pytree_node_class
 class DenseExchange:
     """Backend 1: edge-list gathers for the single-program executors.
 
@@ -171,6 +172,10 @@ class DenseExchange:
     of the pre-refactor executors — for degree-2 graphs those are the same
     two-term additions the ring executor performs, so the executors stay
     bitwise-aligned far longer than matmul gathering would.
+
+    A pytree: its index and degree arrays are the leaves, ``m``, the
+    aggregator and the dtype the static part, so a compiled program can
+    take the exchange as an argument instead of closing over its arrays.
     """
 
     def __init__(self, g, dtype, agg: Callable | None):
@@ -185,6 +190,21 @@ class DenseExchange:
             self.nbr_idx = jnp.asarray(nbr_idx_np)
             self.nbr_mask = jnp.asarray(nbr_mask_np, dtype)
             self.ones_m1 = jnp.ones((g.m, 1), dtype)
+
+    def tree_flatten(self):
+        leaves = (self.src, self.dst, self.deg)
+        if self.agg is not None:
+            leaves += (self.nbr_idx, self.nbr_mask, self.ones_m1)
+        return leaves, (self.m, self.agg, self.dtype)
+
+    @classmethod
+    def tree_unflatten(cls, aux, leaves):
+        ex = object.__new__(cls)
+        ex.m, ex.agg, ex.dtype = aux
+        ex.src, ex.dst, ex.deg = leaves[:3]
+        if ex.agg is not None:
+            ex.nbr_idx, ex.nbr_mask, ex.ones_m1 = leaves[3:]
+        return ex
 
     def edge_diff(self, x):
         """C x per edge: x[s] - x[e] for every edge (s, e)."""

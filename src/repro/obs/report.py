@@ -24,27 +24,32 @@ def _span_breakdown(spans) -> list[dict]:
     """Total wall time per span name, top-level spans only (depth 0), so
     nested segment/snapshot time is not double counted.  ``compiles``
     counts the compile events by kind (the tracer's compile counter) in those
-    spans and every span under them."""
+    spans and every span under them; ``programs`` counts the segment
+    programs reused (``hit``) and built (``miss``) there."""
     spans = list(spans or [])
     totals: dict[str, dict] = {}
     for s in spans:
         if s.get("depth", 0) != 0:
             continue
         row = totals.setdefault(s["name"], {"name": s["name"], "count": 0,
-                                            "total_us": 0.0, "compiles": {}})
+                                            "total_us": 0.0, "compiles": {},
+                                            "programs": {}})
         row["count"] += 1
         row["total_us"] += float(s["dur"])
     by_id = {s["id"]: s for s in spans if "id" in s}
     for s in spans:
-        if not s.get("compiles"):
+        program = s.get("args", {}).get("program")
+        if not s.get("compiles") and program is None:
             continue
         root = s
         while root.get("parent") is not None:
             root = by_id[root["parent"]]
-        counts = totals[root["name"]]["compiles"]
-        for kind, funs in s["compiles"].items():
-            counts[kind] = counts.get(kind, 0) + sum(
+        row = totals[root["name"]]
+        for kind, funs in s.get("compiles", {}).items():
+            row["compiles"][kind] = row["compiles"].get(kind, 0) + sum(
                 n for n, _ in funs.values())
+        if program is not None:
+            row["programs"][program] = row["programs"].get(program, 0) + 1
     return sorted(totals.values(), key=lambda r: -r["total_us"])
 
 
@@ -99,11 +104,14 @@ def render(
         lines += [""]
     if data["time_breakdown"]:
         lines += ["## Time breakdown (top-level spans)", "",
-                  "| span | count | total ms | compile events |",
-                  "| --- | ---: | ---: | --- |"]
+                  "| span | count | total ms | compile events "
+                  "| segment programs |",
+                  "| --- | ---: | ---: | --- | --- |"]
         lines += [
             f"| {r['name']} | {r['count']} | {r['total_us'] / 1e3:.3f} | "
             + ", ".join(f"{k} {n}" for k, n in sorted(r["compiles"].items()))
+            + " | "
+            + ", ".join(f"{k} {n}" for k, n in sorted(r["programs"].items()))
             + " |"
             for r in data["time_breakdown"]
         ]

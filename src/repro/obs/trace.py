@@ -214,6 +214,14 @@ def span(name: str, **args):
     return t.span(name, **args)
 
 
+def annotate(**args) -> None:
+    """Add ``args`` to the record of the innermost open span of the
+    installed tracer (nothing when tracing is off or no span is open)."""
+    t = _ACTIVE
+    if t is not None and t._open:
+        t._open[-1]["args"].update(args)
+
+
 def validate_trace(path) -> int:
     """Check a trace.json loads and its spans nest properly.
 

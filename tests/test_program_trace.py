@@ -4,7 +4,8 @@
   annotation ``obs:<name>``, so a profiler trace holds the program's spans
   beside the device ops, nested as the tracer recorded them.
 * ``use`` counts compile events into the innermost open span, and leaves
-  ``jax.monitoring`` as it found it.
+  ``jax.monitoring`` as it found it; the ``segment`` span says whether
+  its program was built (``miss``) or reused (``hit``).
 * The stream's ``stats`` span holds one ``feed_wait`` per batch and
   changes no bit; with no tracer the stream never blocks.
 * The ADMM round's named scopes reach the lowered program.
@@ -140,11 +141,14 @@ def test_compile_counter_puts_the_scan_trace_on_segment():
     H, T = _data()
     cfg = DMTLELMConfig(r=2, iters=3)
     stats = engine.produce_stats(jnp.asarray(H), jnp.asarray(T))
+    # an empty segment-program cache: this fit builds the program
+    engine.segment_program_cache_clear()
     tracer = obs_trace.Tracer()
     with obs_trace.use(tracer):
         runner = engine.make_runner(stats, ring(4), cfg)
         runner.run()
     (seg,) = _by_name(tracer, "segment")
+    assert seg["args"]["program"] == "miss"
     traces = seg["compiles"]["trace"]
     assert traces["scan"][0] >= 1 and traces["scan"][1] >= 0.0
     assert "jit(scan)" in seg["compiles"]["lower"]
@@ -152,6 +156,25 @@ def test_compile_counter_puts_the_scan_trace_on_segment():
     for s in tracer.spans:
         if s["name"] != "segment":
             assert "scan" not in s.get("compiles", {}).get("trace", {})
+
+
+def test_compile_counter_finds_nothing_to_build_on_a_refit():
+    H, T = _data()
+    cfg = DMTLELMConfig(r=2, iters=3)
+    engine.segment_program_cache_clear()
+    engine.make_runner(engine.produce_stats(jnp.asarray(H), jnp.asarray(T)),
+                       ring(4), cfg).run()
+    # other statistics of the same shapes, graph and config
+    H2, T2 = _data(seed=1)
+    stats = engine.produce_stats(jnp.asarray(H2), jnp.asarray(T2))
+    tracer = obs_trace.Tracer()
+    with obs_trace.use(tracer):
+        engine.make_runner(stats, ring(4), cfg).run()
+    (seg,) = _by_name(tracer, "segment")
+    assert seg["args"]["program"] == "hit"
+    compiles = seg.get("compiles", {})
+    assert "trace" not in compiles and "lower" not in compiles
+    assert engine.segment_program_cache_info() == {"hits": 1, "misses": 1}
 
 
 def test_compile_counter_counts_nothing_for_a_compiled_call():
@@ -196,13 +219,23 @@ def test_compile_counter_outside_any_span_goes_to_the_tracer():
 def test_run_report_counts_compiles_per_top_level_span(tmp_path):
     H, T = _data()
     cfg = DMTLELMConfig(r=2, iters=3)
+    # an empty segment-program cache: the fit builds its program
+    engine.segment_program_cache_clear()
     fit(H, T, ring(4), cfg, trace_dir=tmp_path)
     rep = json.loads((tmp_path / "report.json").read_text())
     rows = {r["name"]: r for r in rep["time_breakdown"]}
     assert rows["segment"]["compiles"]["trace"] >= 1
     assert rows["segment"]["compiles"]["compile"] >= 1
+    assert rows["segment"]["programs"] == {"miss": 1}
     assert rows["sync"]["count"] == 2
     assert "compile events" in (tmp_path / "report.md").read_text()
+    # a refit of the same shapes reuses it
+    fit(H, T, ring(4), cfg, trace_dir=tmp_path)
+    rep = json.loads((tmp_path / "report.json").read_text())
+    rows = {r["name"]: r for r in rep["time_breakdown"]}
+    assert rows["segment"]["programs"] == {"hit": 1}
+    assert "trace" not in rows["segment"]["compiles"]
+    assert "| hit 1 |" in (tmp_path / "report.md").read_text()
 
 
 # --------------------------------------------------------------------------
